@@ -353,6 +353,9 @@ def check() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--smoke" in sys.argv:
         main()
     elif "--check" in sys.argv:

@@ -233,7 +233,7 @@ def _gateway(model, cfg, x, y_ref, n_clients=3):
 
     def client(i):
         eng = gateway_client("127.0.0.1", lst.port, seed=100 + i,
-                             timeout=600)
+                             impl="ref", timeout=600)
         try:
             eng.preprocess(1)
             outs[i] = eng.run(x)
@@ -559,6 +559,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--check" in sys.argv:
         check()
     elif "--smoke" in sys.argv:

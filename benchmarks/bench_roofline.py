@@ -33,4 +33,7 @@ def main(dirname: str = "experiments/dryrun"):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

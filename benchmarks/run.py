@@ -103,6 +103,9 @@ def main(trace: str | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--check" in sys.argv:
         check()
     else:

@@ -82,7 +82,7 @@ def serve_two_party(model, args, rng):
                            TcpTransport)
     from repro.serve import NetPrivateServeEngine
 
-    srv = PitNetServer(model, args.seq, impl="ref")
+    srv = PitNetServer(model, args.seq)
     if args.net == "tcp":
         lst = TcpListener()
         loop = srv.serve_tcp(lst, timeout=600, max_conns=2)
@@ -98,7 +98,7 @@ def serve_two_party(model, args, rng):
         print("two-party over InProcPipe")
 
     eng = NetPrivateServeEngine(off_c, on_c, pool_target=args.requests,
-                                seed=1, impl="ref", timeout=600)
+                                seed=1, timeout=600)
     t0 = perf_counter()
     eng.preprocess(args.requests)
     t_pre = perf_counter() - t0
@@ -172,4 +172,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -83,7 +83,7 @@ def main() -> int:
         port = lst.port
         cli = ResilientClient(
             lambda: plan.wrap(TcpTransport.connect("127.0.0.1", port)),
-            seed=seed,
+            seed=seed, impl="ref",
             policy=RetryPolicy(attempts=6, base_s=0.01, max_s=0.05,
                                seed=seed),
             deadlines=dl)

@@ -105,8 +105,10 @@ def main() -> int:
     gw = PitGateway(model, S, impl="ref", max_sessions=4, pool_cap=4)
     glst = TcpListener()
     gloop = gw.serve_listener(glst, accept_timeout=0.2, timeout=120)
-    e1 = gateway_client("127.0.0.1", glst.port, seed=1, timeout=120)
-    e2 = gateway_client("127.0.0.1", glst.port, seed=2, timeout=120)
+    e1 = gateway_client("127.0.0.1", glst.port, seed=1, impl="ref",
+                        timeout=120)
+    e2 = gateway_client("127.0.0.1", glst.port, seed=2, impl="ref",
+                        timeout=120)
     e1.preprocess(2)  # one to run, one to strand on the kill
     e2.preprocess(1)
     assert np.array_equal(e1.run(x), y_ref), "gateway session 1 diverged"

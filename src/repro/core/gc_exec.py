@@ -221,16 +221,17 @@ class LevelExecutor:
         return jnp.where(mask != 0, free_out ^ rb, free_out)
 
     def _to_kernel(self, x):
-        """block -> (lanes*I, 4) packed kernel layout."""
+        """block -> (4, lanes*I) word planes, lane-major (the kernel's
+        layout; the planar store's blocks already are, as a reshape)."""
         if self.planar:
-            return x.transpose(1, 2, 0).reshape(-1, 4)
-        return x.reshape(-1, 4)
+            return x.reshape(4, -1)
+        return x.transpose(2, 0, 1).reshape(4, -1)
 
     def _from_kernel(self, x, lanes):
         I = self.instances
         if self.planar:
-            return x.reshape(lanes, I, 4).transpose(2, 0, 1)
-        return x.reshape(lanes, I, 4)
+            return x.reshape(4, lanes, I)
+        return x.reshape(4, lanes, I).transpose(1, 2, 0)
 
     # ------------------------------------------------------------------
     # fused-kernel bodies (pallas / pallas_interpret)
@@ -244,22 +245,22 @@ class LevelExecutor:
     def _fused_eval(self, and_ops, a, b, tg, te, slot, fops, fa, fb):
         """Concatenated AND+free block through the fused level kernel.
 
-        Blocks are packed to the kernel's (G, 4) layout and the output
-        unpacked — on TPU these transposes are register shuffles; the
-        CPU ``"jit"`` impl never takes this path.
+        Blocks go to the kernel as (4, G) word planes: a reshape of the
+        planar store's blocks; the row-major (latency) store transposes
+        its few lanes. The CPU ``"jit"`` impl never takes this path.
         """
         I = self.instances
         ca, cf = self.plan.and_width, self.plan.free_width
         ops = self._lanes(jnp.concatenate([and_ops, fops]))
         tw = self._lanes(jnp.concatenate(
             [slot.astype(U32), jnp.zeros((cf,), U32)]))
-        z = jnp.zeros((cf * I, 4), U32)
+        z = jnp.zeros((4, cf * I), U32)
         o = eval_level_pallas(
             ops,
-            jnp.concatenate([self._to_kernel(a), self._to_kernel(fa)], 0),
-            jnp.concatenate([self._to_kernel(b), self._to_kernel(fb)], 0),
-            jnp.concatenate([self._to_kernel(tg), z], 0),
-            jnp.concatenate([self._to_kernel(te), z], 0),
+            jnp.concatenate([self._to_kernel(a), self._to_kernel(fa)], 1),
+            jnp.concatenate([self._to_kernel(b), self._to_kernel(fb)], 1),
+            jnp.concatenate([self._to_kernel(tg), z], 1),
+            jnp.concatenate([self._to_kernel(te), z], 1),
             tw,
             interpret=(self.impl == "pallas_interpret"),
         )

@@ -13,6 +13,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 OP_XOR, OP_AND, OP_INV = 0, 1, 2
 OP_PAD = 3  # padding lane in a compiled level plan (reads/writes dummies)
 OP_NAMES = {OP_XOR: "XOR", OP_AND: "AND", OP_INV: "INV", OP_PAD: "PAD"}
@@ -73,22 +75,11 @@ class Netlist:
         """Topological layers of gate indices: every gate's inputs are
         produced strictly earlier. This is the level-synchronous schedule the
         TPU plane evaluates (gather -> cipher -> scatter per level)."""
-        wire_level = np.zeros(self.num_wires, np.int32)
-        gate_level = np.zeros(self.num_gates, np.int32)
-        for g in range(self.num_gates):
-            a, b = self.in0[g], self.in1[g]
-            l = wire_level[a]
-            if self.op[g] != OP_INV:
-                l = max(l, wire_level[b])
-            gate_level[g] = l + 1
-            wire_level[self.out[g]] = l + 1
-        out = []
-        if self.num_gates:
-            for lvl in range(1, int(gate_level.max()) + 1):
-                idx = np.nonzero(gate_level == lvl)[0]
-                if len(idx):
-                    out.append(idx.astype(np.int32))
-        return out
+        if not self.num_gates:
+            return []
+        level, _ = list_schedule(self)
+        order = np.argsort(level, kind="stable").astype(np.int32)
+        return np.split(order, np.cumsum(np.bincount(level))[:-1])
 
     def and_gate_index(self) -> np.ndarray:
         """Per-gate index among AND gates (for garbled-table addressing)."""
@@ -265,6 +256,101 @@ class LevelPlan:
         return pos.astype(np.int64)
 
 
+def list_schedule(net: Netlist, and_cap: Optional[int] = None,
+                  free_cap: Optional[int] = None
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """Greedy list scheduling under per-class lane capacity.
+
+    Every gate, in gate order, lands in the earliest chunk after all its
+    inputs that still has a spare lane of its class (AND or free), taking
+    the next lane there. Returns ``(chunk_of, lane_of)`` per gate. With no
+    caps this is ASAP levelization (chunk = topological level - 1).
+
+    Run chunk by chunk over a ready frontier, which gives the same
+    placement as the gate-by-gate greedy: the gates chunk ``c`` receives
+    are, per class, the lowest-numbered ``cap`` gates whose inputs all sit
+    in earlier chunks and that no earlier chunk took. The work is numpy
+    per chunk instead of Python per gate (a 7M-gate LayerNorm netlist).
+    """
+    G = net.num_gates
+    chunk_of = np.full(G, -1, np.int64)
+    lane_of = np.zeros(G, np.int64)
+    if not G:
+        return chunk_of, lane_of
+    # gate -> gate dependency edges (one per read of a gate output)
+    prod = np.full(net.num_wires, -1, np.int64)
+    prod[net.out] = np.arange(G)
+    src = np.concatenate([prod[net.in0],
+                          np.where(net.op != OP_INV, prod[net.in1], -1)])
+    dst = np.tile(np.arange(G), 2)
+    keep = src >= 0
+    src, dst = src[keep], dst[keep]
+    order = np.argsort(src, kind="stable")
+    consumers = dst[order]
+    ptr = np.concatenate([[0], np.cumsum(np.bincount(src, minlength=G))])
+    pending = np.bincount(dst, minlength=G)
+    is_and = net.op == OP_AND
+    caps = (and_cap or G, free_cap or G)
+    ready = np.flatnonzero(pending == 0)
+    pools = [_ReadyPool(ready[is_and[ready]]),
+             _ReadyPool(ready[~is_and[ready]])]
+    c = 0
+    while len(pools[0]) or len(pools[1]):
+        taken = []
+        for pool, cap in zip(pools, caps):
+            t = pool.take(cap)
+            chunk_of[t] = c
+            lane_of[t] = np.arange(len(t))
+            taken.append(t)
+        placed = np.concatenate(taken)
+        n = ptr[placed + 1] - ptr[placed]
+        if n.sum():
+            first = np.repeat(ptr[placed] - np.cumsum(n) + n, n)
+            cons, cnt = np.unique(
+                consumers[first + np.arange(int(n.sum()))],
+                return_counts=True)
+            pending[cons] -= cnt
+            new = cons[pending[cons] == 0]
+            pools[0].add(new[is_and[new]])
+            pools[1].add(new[~is_and[new]])
+        c += 1
+    return chunk_of, lane_of
+
+
+class _ReadyPool:
+    """Sorted set of ready gate ids: a large sorted base plus a small
+    sorted overflow, merged into the base once it grows, so taking the
+    lowest ids costs O(cap) and adding costs O(overflow) per chunk."""
+
+    _MERGE = 1 << 16
+
+    def __init__(self, ids: np.ndarray):
+        self.base = np.sort(ids)
+        self.extra = np.zeros(0, np.int64)
+
+    def __len__(self) -> int:
+        return len(self.base) + len(self.extra)
+
+    def add(self, ids: np.ndarray) -> None:
+        if len(ids):
+            self.extra = np.sort(np.concatenate([self.extra, ids]))
+            if len(self.extra) > self._MERGE:
+                self.base = np.sort(np.concatenate([self.base, self.extra]),
+                                    kind="stable")
+                self.extra = self.extra[:0]
+
+    def take(self, cap: int) -> np.ndarray:
+        """Remove and return the ``cap`` lowest ids (ascending)."""
+        cand = np.sort(np.concatenate([self.base[:cap], self.extra[:cap]]))
+        cand = cand[:cap]
+        if not len(cand):
+            return cand
+        nb = int(np.searchsorted(self.base[:cap], cand[-1], side="right"))
+        self.base = self.base[nb:]
+        self.extra = self.extra[len(cand) - nb:]
+        return cand
+
+
 #: instance count at or below which the latency regime applies — wider
 #: chunks (here) and the row-major store layout (``gc_exec``)
 LATENCY_MAX_INSTANCES = 8
@@ -318,6 +404,33 @@ def _chunk_widths(net: Netlist, depth: int,
     return ca, cf
 
 
+def _first_fit(blocked: np.ndarray, busy: np.ndarray, B: int,
+               length: int) -> int:
+    """Lowest start of ``length`` consecutive unblocked rows; rows past the
+    end of ``blocked`` count as free. ``busy[j]`` counts blocked rows in
+    rows ``[j*B, (j+1)*B)``, and ``length >= B``: a window is a run of
+    empty blocks plus the free tail of the block before it and the free
+    head of the block after it."""
+    free = np.concatenate([[False], busy == 0, [False]])
+    edges = np.flatnonzero(free[1:] != free[:-1])
+    starts, ends = edges[0::2], edges[1::2]
+    nb = len(busy)
+    for j0, j1 in zip(starts, ends):
+        if (j1 - j0 + 2) * B < length + 2 and j1 < nb:
+            continue
+        tail = 0
+        if j0:
+            blk = blocked[(j0 - 1) * B: j0 * B]
+            tail = B - 1 - int(np.flatnonzero(blk)[-1])
+        if j1 == nb:
+            return j0 * B - tail
+        head = int(np.argmax(blocked[j1 * B: (j1 + 1) * B]))
+        if tail + (j1 - j0) * B + head >= length:
+            return j0 * B - tail
+    last = np.flatnonzero(blocked[(nb - 1) * B:])
+    return (nb - 1) * B + int(last[-1]) + 1
+
+
 def _allocate_rows_liveness(net: Netlist, K: int, stride: int, n_src: int,
                             chunk_of: np.ndarray, row_off: np.ndarray,
                             ) -> Tuple[np.ndarray, int]:
@@ -352,26 +465,36 @@ def _allocate_rows_liveness(net: Netlist, K: int, stride: int, n_src: int,
         np.zeros(K, np.int64)
     bounds = np.concatenate([[0], np.cumsum(counts)])
 
-    release = np.zeros(n_src + 4 * stride, np.int64)
-    release[:n_src] = INF  # sources pinned: garble reads them at the end
+    # rows above the sources: ``blocked`` marks rows holding a live
+    # label; a row frees at the chunk its last reader runs (bucketed by
+    # that chunk). ``busy`` counts blocked rows per B-row block, so the
+    # first-fit search walks runs of empty blocks instead of every row.
+    B = max(1, stride // 4)
+    blocked = np.zeros((4 * stride // B + 1) * B, bool)
+    busy = np.zeros(len(blocked) // B, np.int64)
+    frees: Dict[int, List[np.ndarray]] = {}
     base = np.empty(K, np.int64)
     for k in range(K):
-        while True:
-            blocked = release[n_src:] > k
-            if len(blocked) >= stride:
-                csum = np.cumsum(blocked)
-                wsum = csum[stride - 1:].copy()
-                wsum[1:] -= csum[:-stride]
-                free_at = np.flatnonzero(wsum == 0)
-                if len(free_at):
-                    break
-            release = np.concatenate(
-                [release,
-                 np.zeros(max(stride, len(release) // 4), np.int64)])
-        b = int(n_src + free_at[0])
-        base[k] = b
+        for rows in frees.pop(k, ()):
+            blocked[rows] = False
+            np.subtract.at(busy, rows // B, 1)
+        b = _first_fit(blocked, busy, B, stride)
+        if b + stride > len(blocked):
+            grow = max(b + stride, 2 * len(blocked)) // B * B + B
+            blocked = np.concatenate(
+                [blocked, np.zeros(grow - len(blocked), bool)])
+            busy = np.concatenate(
+                [busy, np.zeros(grow // B - len(busy), np.int64)])
+        base[k] = n_src + b
         g_k = order[bounds[k]: bounds[k + 1]]
-        release[b + row_off[g_k]] = live_until[g_k]
+        until = live_until[g_k]
+        live = until > k
+        rows = b + row_off[g_k[live]]
+        blocked[rows] = True
+        np.add.at(busy, rows // B, 1)
+        until = until[live]
+        for r in np.unique(until[until < K]):
+            frees.setdefault(int(r), []).append(rows[until == r])
     n_rows = int(base.max(initial=n_src) + stride) + 1 if K else \
         n_src + stride + 1
     return base, n_rows
@@ -470,7 +593,6 @@ def compile_level_plan(net: Netlist,
     packing/encoding purposes (a garble-width plan's tables feed an
     eval-width plan's evaluate bit-exactly).
     """
-    W, nA, G = net.num_wires, net.and_count, net.num_gates
     depth = getattr(net, "_plan_depth", None)
     if depth is None:
         depth = len(net.levels())
@@ -484,44 +606,28 @@ def compile_level_plan(net: Netlist,
     cached = plans.get((ca, cf, bool(compact)))
     if cached is not None:
         return cached
+    with obs.span("netlist.plan", netlist=net.name, and_width=ca,
+                  free_width=cf, compact=bool(compact)):
+        plan = _compile_level_plan(net, depth, ca, cf, compact)
+    plans[(ca, cf, bool(compact))] = plan
+    return plan
 
+
+def _compile_level_plan(net: Netlist, depth: int, ca: int, cf: int,
+                        compact: bool) -> LevelPlan:
+    W, nA, G = net.num_wires, net.and_count, net.num_gates
     op, in0, in1, out = net.op, net.in0, net.in1, net.out
-    # greedy list scheduling under per-class lane capacity: every gate
-    # lands in the earliest chunk after all its inputs with a spare lane
-    wire_chunk = np.full(W, -1, np.int64)
-    fill_and: List[int] = []
-    fill_free: List[int] = []
-    chunk_of = np.empty(G, np.int64)
-    lane_of = np.empty(G, np.int64)
-    for g in range(G):
-        e = wire_chunk[in0[g]] + 1
-        if op[g] != OP_INV:
-            e = max(e, wire_chunk[in1[g]] + 1)
-        is_and = op[g] == OP_AND
-        fill, cap = (fill_and, ca) if is_and else (fill_free, cf)
-        c = e
-        while c < len(fill) and fill[c] >= cap:
-            c += 1
-        while c >= len(fill):
-            fill_and.append(0)
-            fill_free.append(0)
-        lane_of[g] = fill[c]
-        fill[c] += 1
-        chunk_of[g] = c
-        wire_chunk[out[g]] = c
-
-    K = max(len(fill_and), 1)
+    chunk_of, lane_of = list_schedule(net, ca, cf)
+    is_and_g = op == OP_AND
+    K = int(chunk_of.max()) + 1 if G else 1
+    and_valid = np.bincount(chunk_of[is_and_g], minlength=K)
+    free_valid = np.bincount(chunk_of[~is_and_g], minlength=K)
     stride = ca + cf
-    and_valid = np.zeros(K, np.int64)
-    and_valid[: len(fill_and)] = fill_and
-    free_valid = np.zeros(K, np.int64)
-    free_valid[: len(fill_free)] = fill_free
 
     src = np.ones(W, bool)
     src[out] = False
     source_ids = np.nonzero(src)[0].astype(np.int64)
     n_src = len(source_ids)
-    is_and_g = op == OP_AND
     # per-gate row offset inside its chunk's write block (AND lanes first)
     row_off = (np.where(is_and_g, lane_of,
                         and_valid[chunk_of] + lane_of).astype(np.int64)
@@ -620,7 +726,6 @@ def compile_level_plan(net: Netlist,
     # always-on invariant check: a bad renumber is a silent wrong-label
     # disaster, so every freshly compiled plan is simulated once
     _validate_plan(net, plan, chunk_of, lane_of)
-    plans[(ca, cf, bool(compact))] = plan
     return plan
 
 

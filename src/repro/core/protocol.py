@@ -13,8 +13,9 @@ Layer menu:
   gc_apply      — garbled nonlinear function with share reconstruct/remask
   trunc         — exact deferred rescale through a tiny identity circuit
   layernorm     — full-GC baseline  OR  APINT offload (Fig. 4 ⑦–⑬):
-                  mean/center on shares, variance via the HE inner-product
-                  identity, β/γ affine via HE slots, only rsqrt·mul in GC.
+                  exact centring on shares (n·x − Σx), Σx² via the HE
+                  inner-product identity, γ/n and β via HE slots; the
+                  variance from Σx, Σx² and rsqrt·mul in GC.
 
 Every layer is split into an explicit ``*_offline(...) -> correlation`` /
 ``*_online(x, correlation)`` pair. Offline methods depend only on shapes
@@ -227,10 +228,9 @@ class LayerNormCorrelation:
     offload: bool
     gc: GCCorrelation
     bq: np.ndarray
-    gq: Optional[np.ndarray] = None  # offload: γ at scale f
+    gq: Optional[np.ndarray] = None  # offload: γ/n (ln_gamma_scale)
     raw_e: Optional[np.ndarray] = None  # full-GC: (I, 2n) γ/β words
     he_mask: Optional[np.ndarray] = None  # offload: inner-product mask
-    inv_n: int = 0
     in_scale: int = 0
 
 
@@ -409,14 +409,16 @@ class PiTProtocol:
         """
         if name in self._netlist_cache:
             return self._netlist_cache[name]
-        cb = CircuitBuilder(name)
-        ins = [input_shared_word(cb, self.t, descale) for _ in range(n_in)]
-        raws = [cb.e_input_word(self.k) for _ in range(n_raw_e)]
-        outs = body(cb, ins, raws) if n_raw_e else body(cb, ins)
-        assert len(outs) == n_out
-        for y in outs:
-            output_shared(cb, Word(y.bits[: self.k]), self.t)
-        net = cb.build()
+        with obs.span("netlist.build", netlist=name):
+            cb = CircuitBuilder(name)
+            ins = [input_shared_word(cb, self.t, descale)
+                   for _ in range(n_in)]
+            raws = [cb.e_input_word(self.k) for _ in range(n_raw_e)]
+            outs = body(cb, ins, raws) if n_raw_e else body(cb, ins)
+            assert len(outs) == n_out
+            for y in outs:
+                output_shared(cb, Word(y.bits[: self.k]), self.t)
+            net = cb.build()
         self._netlist_cache[name] = net
         return net
 
@@ -605,12 +607,17 @@ class PiTProtocol:
         )
 
     def layernorm_reduced_net(self, n: int, in_scale: int) -> Netlist:
-        f = self.frac
-        sc_ = in_scale + f
         return self.build_fn_circuit(
-            f"layernorm_reduced{n}_s{in_scale}", n + 1, n,
-            _make_ln_reduced(f, self.style, 2 * sc_, sc_), descale=0,
+            f"layernorm_reduced{n}_s{in_scale}", n + 2, n,
+            _make_ln_reduced(n, self.frac, self.style, in_scale), descale=0,
         )
+
+    def ln_gamma_mod(self, gamma, n: int) -> np.ndarray:
+        """γ/n at scale :func:`ln_gamma_scale` as residues mod t: the
+        weights the offloaded LayerNorm multiplies the centred shares by
+        (1/n folded in, so centring needs no division)."""
+        return SS.encode_fx(np.asarray(gamma, np.float64) / n,
+                            ln_gamma_scale(n, self.frac), self.t)
 
     # ------------------------------------------------------------------
     # composite layers: offline/online pairs + compat wrappers
@@ -678,21 +685,19 @@ class PiTProtocol:
         # ---- APINT Fig. 4, offline legs -------------------------------
         with st.phase("offline"), \
                 obs.span("layernorm_offline", n=n, instances=I):
-            inv_n = int(round((1 << f) / n))
-            gq = SS.encode_fx(np.asarray(gamma), f, t)
+            gq = self.ln_gamma_mod(gamma, n)
             bq = SS.encode_fx(np.asarray(beta), f, t)
             # ⑩ Enc(R2') for the γ⊙r' slot products is sent ahead of time
             ct_blocks = math.ceil(I * n / self.params.n)
             st.channel_offline.c2s(ct_blocks * self._ct_bytes, "he-ln-r")
             st.he_pt_muls += ct_blocks
-            # ⑧ Enc of the client's centered share for the inner product
+            # ⑧ Enc of the client's share row for the inner product
             st.channel_offline.c2s(I * self._ct_bytes, "he-enc-centered")
             st.he_encrypts += I
             he_mask = self.rng.integers(0, t, I, dtype=np.uint64)
         gcc = self.gc_offline(self.layernorm_reduced_net(n, in_scale), I, n, gcirc)
         return LayerNormCorrelation(offload=True, gc=gcc, gq=gq, bq=bq,
-                                    he_mask=he_mask, inv_n=inv_n,
-                                    in_scale=in_scale)
+                                    he_mask=he_mask, in_scale=in_scale)
 
     def layernorm_online(self, corr: LayerNormCorrelation, xc, xs):
         t = self.t
@@ -705,29 +710,27 @@ class PiTProtocol:
         with st.phase("online"), \
                 obs.span("layernorm_online", n=int(xc.shape[1]),
                          instances=int(xc.shape[0])):
-            I, n = xc.shape
-            f = self.frac
-            in_scale = corr.in_scale
-            # ⑦ mean & center on shares (standard local ops): ×round(2^f/n)
-            mu_c = SS.scalar_mul_mod(corr.inv_n, _row_sum(xc, t), t)
-            mu_s = SS.scalar_mul_mod(corr.inv_n, _row_sum(xs, t), t)
-            # centered x' at scale Sc = in_scale + f
-            cxc = SS.sub_mod(SS.scalar_mul_mod(1 << f, xc, t), mu_c[:, None], t)
-            cxs = SS.sub_mod(SS.scalar_mul_mod(1 << f, xs, t), mu_s[:, None], t)
-            # ⑧⑨ variance via HE inner product: Σx'² = Σu² + 2⟨u, r'⟩ + Σr'²
-            cross_c, cross_s = self._he_inner_online(cxc, cxs, corr.he_mask)
-            var_c = SS.add_mod(_row_sum_sq(cxc, t),
-                               SS.scalar_mul_mod(2, cross_c, t), t)
-            var_s = SS.add_mod(_row_sum_sq(cxs, t),
-                               SS.scalar_mul_mod(2, cross_s, t), t)
-            var_c = SS.scalar_mul_mod(corr.inv_n, var_c, t)  # scale 2·Sc + f
-            var_s = SS.scalar_mul_mod(corr.inv_n, var_s, t)
-            # ⑩⑪ γ·x' via HE slots: γ⊙r' offline, γ⊙u server-local
-            gxc = _rowwise_mul(corr.gq, cxc, t)
-            gxs = _rowwise_mul(corr.gq, cxs, t)
-            in_c = np.concatenate([gxc, var_c[:, None]], axis=1)
-            in_s = np.concatenate([gxs, var_s[:, None]], axis=1)
-        # ⑫ reduced GC: rsqrt(var) × (γ·x')
+            n = xc.shape[1]
+            # ⑦ centre on shares, exactly: u = n·x − Σx = n·(x − μ).
+            # Dividing by n here (×round(2^f/n)) underflows once
+            # n > 2^(f+1) — BERT-base's n=768 at f=7 — and centring at a
+            # finer scale overflows the ring in Σu². So the shares carry
+            # n·(x − μ), and the circuit divides by n.
+            s1_c, s1_s = _row_sum(xc, t), _row_sum(xs, t)
+            uc = SS.sub_mod(SS.scalar_mul_mod(n, xc, t), s1_c[:, None], t)
+            us = SS.sub_mod(SS.scalar_mul_mod(n, xs, t), s1_s[:, None], t)
+            # ⑧⑨ Σx² via the HE inner product: Σc² + 2⟨c, s⟩ + Σs²
+            cross_c, cross_s = self._he_inner_online(xc, xs, corr.he_mask)
+            s2_c = SS.add_mod(_row_sum_sq(xc, t),
+                              SS.scalar_mul_mod(2, cross_c, t), t)
+            s2_s = SS.add_mod(_row_sum_sq(xs, t),
+                              SS.scalar_mul_mod(2, cross_s, t), t)
+            # ⑩⑪ (γ/n)·u via HE slots: γ⊙r' offline, γ⊙u server-local
+            gxc = _rowwise_mul(corr.gq, uc, t)
+            gxs = _rowwise_mul(corr.gq, us, t)
+            in_c = np.concatenate([gxc, s1_c[:, None], s2_c[:, None]], axis=1)
+            in_s = np.concatenate([gxs, s1_s[:, None], s2_s[:, None]], axis=1)
+        # ⑫ reduced GC: var from the moments, rsqrt(var) × (γ/n)·u
         oc, os_ = self.gc_online(corr.gc, in_c, in_s)
         with st.phase("online"):
             # ⑬ + β (server-held parameter added to its share)
@@ -881,23 +884,42 @@ def _layernorm_body(cb, ins, frac, style, gammas, betas):
     return outs
 
 
-def _make_ln_reduced(frac, style, var_descale, x_descale):
+def ln_gamma_scale(n: int, frac: int) -> int:
+    """Scale of the γ/n weights of the offloaded LayerNorm: 8 bits of
+    precision beyond γ's own ``frac`` after the 1/n."""
+    return frac + max(n - 1, 1).bit_length() + 8
+
+
+def _make_ln_reduced(n, frac, style, in_scale):
+    """Reduced LayerNorm body over ``n + 2`` reconstructed words:
+    ``(γ/n)·u_i`` at scale ``in_scale + ln_gamma_scale`` (u = n·(x − μ)),
+    then Σx at ``in_scale`` and Σx² at ``2·in_scale``."""
+    s = in_scale
+    if 2 * s < frac:
+        raise ValueError("LayerNorm input scale below frac/2")
+    # var at scale f = (n·Σx² − (Σx)²) · 2^(f − 2s) / n²: shift to scale
+    # f first, then × round(2^b / n²) >> b, with 8 bits of precision
+    # beyond 1/n² in the constant
+    b = (n * n - 1).bit_length() + 8
+    inv_n2 = round((1 << b) / (n * n))
+    x_descale = s + ln_gamma_scale(n, frac) - frac
+
     def body(cb, ins):
-        xs, var = ins[:-1], ins[-1]
-        var = arith.shift_right_const(cb, var, var_descale, arithmetic=True)
+        gus, s1, s2 = ins[:-2], ins[-2], ins[-1]
+        d = arith.sub(cb, arith.mul_const(cb, s2, n),
+                      arith.fx_mul(cb, s1, s1, 0, style=style))
+        var = arith.shift_right_const(cb, d, 2 * s - frac, arithmetic=True)
+        var = arith.shift_right_const(
+            cb, arith.mul_const(cb, var, inv_n2), b, arithmetic=True)
         var = arith.add_const(cb, var, 1)
         rs = NL.rsqrt_circuit(cb, var, frac, style)
         outs = []
-        for x in xs:
-            xd = arith.shift_right_const(cb, x, x_descale, arithmetic=True)
+        for gu in gus:
+            xd = arith.shift_right_const(cb, gu, x_descale, arithmetic=True)
             outs.append(arith.fx_mul(cb, xd, rs, frac, style=style))
         return outs
 
     return body
-
-
-def _ln_reduced_body(cb, ins, frac, style):  # kept for direct benching
-    return _make_ln_reduced(frac, style, 0, 0)(cb, ins)
 
 
 # ---------------------------------------------------------------------------

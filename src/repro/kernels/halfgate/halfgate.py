@@ -18,12 +18,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.halfgate import ref
 
 DEFAULT_BLOCK = 2048
 U32 = jnp.uint32
+_Z = np.int32(0)  # index-map constant: Mosaic wants i32 block indices
 
 
 def _pad_gates(x, block):
@@ -53,11 +55,11 @@ def _eval_kernel(a_ref, b_ref, tg_ref, te_ref, tw_ref, c_ref):
 
 
 def _label_spec(block):
-    return pl.BlockSpec((block, 4), lambda i: (i, 0))
+    return pl.BlockSpec((block, 4), lambda i: (i, _Z))
 
 
 def _tweak_spec(block):
-    return pl.BlockSpec((block, 1), lambda i: (i, 0))
+    return pl.BlockSpec((block, 1), lambda i: (i, _Z))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

@@ -11,10 +11,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK = 4096
 U32 = jnp.uint32
+_Z = np.int32(0)  # index-map constant: Mosaic wants i32 block indices
 
 
 def _kernel(w0_ref, r_ref, bits_ref, out_ref):
@@ -46,11 +48,11 @@ def select_labels_pallas(w0, r, bits, *, block=DEFAULT_BLOCK, interpret=False):
         _kernel,
         grid=(gp // blk,),
         in_specs=[
-            pl.BlockSpec((blk, 4), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 4), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+            pl.BlockSpec((blk, 4), lambda i: (i, _Z)),
+            pl.BlockSpec((blk, 4), lambda i: (i, _Z)),
+            pl.BlockSpec((blk, 1), lambda i: (i, _Z)),
         ],
-        out_specs=pl.BlockSpec((blk, 4), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((blk, 4), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((gp, 4), U32),
         interpret=interpret,
     )(w0p, rp, bp)

@@ -20,13 +20,14 @@ def eval_level(ops, a, b, tg, te, tweaks, impl: str = "auto"):
     impl = resolve_impl(impl)
     if impl in ("ref", "jit"):
         return _ref.eval_level(ops, a, b, tg, te, tweaks)
-    return eval_level_pallas(ops, a, b, tg, te, tweaks,
-                             interpret=(impl == "pallas_interpret"))
+    return eval_level_pallas(ops, a.T, b.T, tg.T, te.T, tweaks,
+                             interpret=(impl == "pallas_interpret")).T
 
 
 def garble_level(ops, a0, b0, r, tweaks, impl: str = "auto"):
     impl = resolve_impl(impl)
     if impl in ("ref", "jit"):
         return _ref.garble_level(ops, a0, b0, r, tweaks)
-    return garble_level_pallas(ops, a0, b0, r, tweaks,
-                               interpret=(impl == "pallas_interpret"))
+    c0, tg, te = garble_level_pallas(ops, a0.T, b0.T, r.T, tweaks,
+                                     interpret=(impl == "pallas_interpret"))
+    return c0.T, tg.T, te.T

@@ -59,3 +59,36 @@ def garble_level(ops, a0, b0, r, tweaks):
         jnp.where(is_and, tg, zero),
         jnp.where(is_and, te, zero),
     )
+
+
+def eval_level_planar(ops, a, b, tg, te, tweaks):
+    """:func:`eval_level` on word planes: labels/tables are 4-tuples of
+    equal-shape uint32 planes, ``ops``/``tweaks`` of that shape too.
+    Returns the 4-tuple of output planes (bit-identical per gate)."""
+    and_out = HG.eval_and_split(a, b, tg, te, tweaks)
+    is_and = ops == U32(1)
+    is_inv = ops == U32(2)
+    is_pad = ops >= U32(3)
+    out = []
+    for k in range(4):
+        o = jnp.where(is_and, and_out[k], a[k] ^ b[k])
+        o = jnp.where(is_inv, a[k], o)
+        out.append(jnp.where(is_pad, U32(0), o))
+    return tuple(out)
+
+
+def garble_level_planar(ops, a0, b0, r, tweaks):
+    """:func:`garble_level` on word planes (see :func:`eval_level_planar`).
+    Returns ``(c0, tg, te)``, each a 4-tuple of planes."""
+    c_and, tg, te = HG.garble_and_split(a0, b0, r, tweaks)
+    is_and = ops == U32(1)
+    is_inv = ops == U32(2)
+    is_pad = ops >= U32(3)
+    c0, tgo, teo = [], [], []
+    for k in range(4):
+        c = jnp.where(is_and, c_and[k], a0[k] ^ b0[k])
+        c = jnp.where(is_inv, a0[k] ^ r[k], c)
+        c0.append(jnp.where(is_pad, U32(0), c))
+        tgo.append(jnp.where(is_and, tg[k], U32(0)))
+        teo.append(jnp.where(is_and, te[k], U32(0)))
+    return tuple(c0), tuple(tgo), tuple(teo)

@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from repro.kernels.ntt.ref import ntt_tables
 
 DEFAULT_BLOCK = 8
+_Z = np.int32(0)  # index-map constant: Mosaic wants i32 block indices
 
 
 def _mulmod(a, b, q):
@@ -110,10 +111,10 @@ def ntt_pallas(a, q: int, n: int, *, inverse: bool = False,
         kern,
         grid=(bp // block,),
         in_specs=[
-            pl.BlockSpec((block, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            pl.BlockSpec((block, n), lambda i: (i, _Z)),
+            pl.BlockSpec((1, n), lambda i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((block, n), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((block, n), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((bp, n), jnp.int32),
         interpret=interpret,
     )(af, table)

@@ -574,7 +574,7 @@ class ServerShared:
     namespace that ``PitNetServer`` endpoints use.
     """
 
-    def __init__(self, model, seq_len: int, *, impl: str = "ref",
+    def __init__(self, model, seq_len: int, *, impl: str = "auto",
                  seed: int = 104729, wire_version: int = W.WIRE_V2,
                  compression: bool = True):
         self.model = model
@@ -631,7 +631,7 @@ class ServerShared:
             beta = getattr(Wt, f"{which}_b")
             f = p.frac
             self._ln_cache[op.name] = {
-                "gq_mod": SS.encode_fx(np.asarray(gamma), f, p.t),
+                "gq_mod": p.ln_gamma_mod(gamma, len(gamma)),
                 "bq_mod": SS.encode_fx(np.asarray(beta), f, p.t),
                 "gq_raw": np.round(np.asarray(gamma, np.float64) * (1 << f)
                                    ).astype(np.int64),
@@ -667,7 +667,7 @@ class EvaluatorEndpoint(_Endpoint):
 
     def __init__(self, transport: Transport, *, model=None,
                  seq_len: Optional[int] = None,
-                 shared: Optional[ServerShared] = None, impl: str = "ref",
+                 shared: Optional[ServerShared] = None, impl: str = "auto",
                  timeout: Optional[float] = None,
                  deadlines: Optional[Deadlines] = None,
                  session: Optional[SessionState] = None):
@@ -1155,7 +1155,7 @@ class EvaluatorEndpoint(_Endpoint):
                           ) -> np.ndarray:
         sh = self.shared
         p = sh.protocol
-        t, f = p.t, p.frac
+        t = p.t
         I, n = hs.shape
         lp = sh.ln_params(op)
         if not p.pcfg.layernorm_offload:
@@ -1164,23 +1164,20 @@ class EvaluatorEndpoint(_Endpoint):
                                  axis=1)
             return self._server_gc(part, hs, raw)
         # APINT Fig. 4 offload, evaluator legs (mirrors layernorm_online)
-        inv_n = int(round((1 << f) / n))
-        mu = SS.scalar_mul_mod(inv_n, _row_sum(hs, t), t)
-        cxs = SS.sub_mod(SS.scalar_mul_mod(1 << f, hs, t), mu[:, None], t)
-        cxc = np.asarray(self._expect_msg(W.KIND_SIM, "ln-centered"),
-                         np.uint64)
+        s1 = _row_sum(hs, t)
+        us = SS.sub_mod(SS.scalar_mul_mod(n, hs, t), s1[:, None], t)
+        hc = np.asarray(self._expect_msg(W.KIND_SIM, "ln-share"), np.uint64)
         cross = np.array(
-            [int(np.dot(cxc[i].astype(object), cxs[i].astype(object)) % t)
+            [int(np.dot(hc[i].astype(object), hs[i].astype(object)) % t)
              for i in range(I)], dtype=np.uint64)
         cross_c = SS.sub_mod(cross, part["he_mask"], t)
         self._send_segs([W.Seg("he-cross", W.DIR_S2C,
                                W.ct_pack_rows(cross_c, p._ct_bytes))],
                         W.PHASE_ONLINE)
-        var_s = SS.add_mod(_row_sum_sq(cxs, t),
-                           SS.scalar_mul_mod(2, part["he_mask"], t), t)
-        var_s = SS.scalar_mul_mod(inv_n, var_s, t)
-        gxs = _rowwise_mul(lp["gq_mod"], cxs, t)
-        in_s = np.concatenate([gxs, var_s[:, None]], axis=1)
+        s2 = SS.add_mod(_row_sum_sq(hs, t),
+                        SS.scalar_mul_mod(2, part["he_mask"], t), t)
+        gxs = _rowwise_mul(lp["gq_mod"], us, t)
+        in_s = np.concatenate([gxs, s1[:, None], s2[:, None]], axis=1)
         out = self._server_gc(part, in_s, None)
         return SS.add_mod(out, np.broadcast_to(lp["bq_mod"], out.shape), t)
 
@@ -1194,7 +1191,7 @@ class ClientShared:
     """Input-owner state shared by a client's endpoints (offline + online
     pairs in the pipelined mode): protocol, plan, and the bundle pool."""
 
-    def __init__(self, *, seed: int = 0, impl: str = "ref",
+    def __init__(self, *, seed: int = 0, impl: str = "auto",
                  wire_version: int = W.WIRE_V2, compression: bool = True):
         self.seed = seed
         self.impl = impl
@@ -1273,7 +1270,7 @@ class GarblerEndpoint(_Endpoint):
 
     def __init__(self, transport: Transport, *,
                  shared: Optional[ClientShared] = None, seed: int = 0,
-                 impl: str = "ref", timeout: Optional[float] = None,
+                 impl: str = "auto", timeout: Optional[float] = None,
                  deadlines: Optional[Deadlines] = None,
                  wire_version: int = W.WIRE_V2, compression: bool = True,
                  reset_ot: bool = False, gen: int = 0):
@@ -1609,23 +1606,21 @@ class GarblerEndpoint(_Endpoint):
                           ) -> np.ndarray:
         sh = self.shared
         p = sh.protocol
-        t, f = p.t, p.frac
+        t = p.t
         I, n = hc.shape
         if not p.pcfg.layernorm_offload:
             return self._client_gc(part, hc)
-        inv_n = int(round((1 << f) / n))
-        mu = SS.scalar_mul_mod(inv_n, _row_sum(hc, t), t)
-        cxc = SS.sub_mod(SS.scalar_mul_mod(1 << f, hc, t), mu[:, None], t)
-        # sim sideband: the oracle prepays the centered-share ciphertext
+        s1 = _row_sum(hc, t)
+        uc = SS.sub_mod(SS.scalar_mul_mod(n, hc, t), s1[:, None], t)
+        # sim sideband: the oracle prepays the share-row ciphertext
         # offline ("he-enc-centered"); the actual coefficients ride here
-        self._send_sim("ln-centered", cxc, W.PHASE_ONLINE)
+        self._send_sim("ln-share", hc, W.PHASE_ONLINE)
         cross_c = W.ct_unpack_rows(self._expect_seg("he-cross"), I,
                                    p._ct_bytes)
-        var_c = SS.add_mod(_row_sum_sq(cxc, t),
-                           SS.scalar_mul_mod(2, cross_c, t), t)
-        var_c = SS.scalar_mul_mod(inv_n, var_c, t)
-        gxc = _rowwise_mul(sh.ln_gq[op.name], cxc, t)
-        in_c = np.concatenate([gxc, var_c[:, None]], axis=1)
+        s2 = SS.add_mod(_row_sum_sq(hc, t),
+                        SS.scalar_mul_mod(2, cross_c, t), t)
+        gxc = _rowwise_mul(sh.ln_gq[op.name], uc, t)
+        in_c = np.concatenate([gxc, s1[:, None], s2[:, None]], axis=1)
         return self._client_gc(part, in_c)
 
 
@@ -1642,7 +1637,7 @@ class PitNetServer:
     ``run`` traffic (see ``serve.private_engine.NetPrivateServeEngine``).
     """
 
-    def __init__(self, model, seq_len: int, *, impl: str = "ref",
+    def __init__(self, model, seq_len: int, *, impl: str = "auto",
                  seed: int = 104729, wire_version: int = W.WIRE_V2,
                  compression: bool = True):
         self.shared = ServerShared(model, seq_len, impl=impl, seed=seed,

@@ -102,7 +102,7 @@ class ResilientClient:
     """
 
     def __init__(self, connect: Callable[[], Transport], *, seed: int = 0,
-                 impl: str = "ref", policy: Optional[RetryPolicy] = None,
+                 impl: str = "auto", policy: Optional[RetryPolicy] = None,
                  timeout: Optional[float] = None,
                  deadlines: Optional[Deadlines] = None,
                  wire_version: int = W.WIRE_V2, compression: bool = True):

@@ -137,7 +137,7 @@ class PitGateway:
     retry-after hint when no preprocessing time has been observed yet.
     """
 
-    def __init__(self, model, seq_len: int, *, impl: str = "ref",
+    def __init__(self, model, seq_len: int, *, impl: str = "auto",
                  seed: int = 104729, max_sessions: int = 8,
                  pool_cap: int = 4, retry_floor_s: float = 0.05,
                  lease_s: float = 0.0,
@@ -463,7 +463,7 @@ class PitGateway:
 
 
 def gateway_client(host: str, port: int, *, pool_target: int = 2,
-                   seed: int = 0, impl: str = "ref",
+                   seed: int = 0, impl: str = "auto",
                    timeout: Optional[float] = None, **shaping):
     """Connect a pipelined client (offline + online transport pair) to a
     gateway and return a ready :class:`NetPrivateServeEngine`. Both

@@ -248,7 +248,7 @@ class NetPrivateServeEngine:
     """
 
     def __init__(self, offline_transport, online_transport, *,
-                 pool_target: int = 2, seed: int = 0, impl: str = "ref",
+                 pool_target: int = 2, seed: int = 0, impl: str = "auto",
                  timeout: Optional[float] = None):
         from repro.net.party import ClientShared, GarblerEndpoint
 

@@ -39,7 +39,8 @@ def _ring_allreduce_int8(x, axis_name: str):
     idx = jax.lax.axis_index(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % n
-    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
     chunks = flat.reshape(n, -1)
 
     # reduce-scatter ring: after n-1 steps, rank r holds the full sum of

@@ -6,17 +6,7 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` with the check kwarg spelled for the installed
-    version (``check_vma`` post-rename, ``check_rep`` before), falling
-    back to ``jax.experimental.shard_map`` when it isn't public yet."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    kw = ("check_vma"
-          if "check_vma" in inspect.signature(impl).parameters
-          else "check_rep")
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **{kw: check})
+    """``jax.shard_map`` with the varying-manual-axes check off by
+    default (the collectives here are written per shard)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -257,7 +257,7 @@ def test_resilient_reconnect_resumes_and_burns():
         conns[0] += 1
         return FaultyTransport(c, schedules.get(i, FaultSchedule()))
 
-    cli = ResilientClient(connect, seed=5,
+    cli = ResilientClient(connect, seed=5, impl="ref",
                           policy=RetryPolicy(attempts=6, base_s=0.02),
                           deadlines=dl)
     rng = np.random.default_rng(72)
@@ -305,7 +305,7 @@ def test_interrupted_prep_retried_with_fresh_ids():
         conns[0] += 1
         return FaultyTransport(c, schedules.get(i, FaultSchedule()))
 
-    cli = ResilientClient(connect, seed=6,
+    cli = ResilientClient(connect, seed=6, impl="ref",
                           policy=RetryPolicy(attempts=6, base_s=0.02),
                           deadlines=dl)
     ids = cli.preprocess(1)
@@ -336,7 +336,7 @@ def test_lease_expiry_reclaims_and_surfaces_session_lost():
         gw.serve_transport(s, deadlines=dl)
         return c
 
-    cli = ResilientClient(connect, seed=7,
+    cli = ResilientClient(connect, seed=7, impl="ref",
                           policy=RetryPolicy(attempts=3, base_s=0.02),
                           deadlines=dl)
     cli.preprocess(1)
@@ -420,7 +420,7 @@ def test_chaos_sweep_typed_or_bit_identical():
             return plan.wrap(c)
 
         cli = ResilientClient(
-            connect, seed=seed,
+            connect, seed=seed, impl="ref",
             policy=RetryPolicy(attempts=6, base_s=0.01, max_s=0.05,
                                seed=seed),
             deadlines=dl)

@@ -38,7 +38,7 @@ def _inproc_engine(gw, *, seed, pool_target=2, timeout=120):
     gw.serve_transport(off_s, timeout=timeout)
     gw.serve_transport(on_s, timeout=timeout)
     return NetPrivateServeEngine(off_c, on_c, pool_target=pool_target,
-                                 seed=seed, timeout=timeout)
+                                 seed=seed, impl="ref", timeout=timeout)
 
 
 def _wait(pred, timeout=20.0, what="condition"):
@@ -107,7 +107,7 @@ def test_gateway_four_tcp_sessions_bit_identical():
     def client(i):
         try:
             eng = gateway_client("127.0.0.1", lst.port, seed=100 + i,
-                                 timeout=120)
+                                 impl="ref", timeout=120)
             engines[i] = eng
             eng.preprocess(1)
             outs[i] = eng.run(xs[i])
@@ -215,7 +215,7 @@ def test_gateway_kill_during_pipelined_refill_reclaims_exactly():
     gw.serve_transport(on_s, timeout=120)
     ft = FaultyTransport(off_c)
     victim = NetPrivateServeEngine(ft, on_c, pool_target=2, seed=1,
-                                   timeout=120)
+                                   impl="ref", timeout=120)
     survivor = _inproc_engine(gw, seed=2)
 
     victim.preprocess(2)
@@ -265,9 +265,11 @@ def test_gateway_session_cap_sheds_with_hint():
     lst = TcpListener()
     loop = gw.serve_listener(lst, accept_timeout=0.2, timeout=60)
 
-    eng = gateway_client("127.0.0.1", lst.port, seed=1, timeout=60)
+    eng = gateway_client("127.0.0.1", lst.port, seed=1, impl="ref",
+                         timeout=60)
     with pytest.raises(BundlePoolEmpty) as ei:
-        gateway_client("127.0.0.1", lst.port, seed=2, timeout=60)
+        gateway_client("127.0.0.1", lst.port, seed=2, impl="ref",
+                       timeout=60)
     assert ei.value.scope == "session"
     assert ei.value.retry_after_s is not None and ei.value.retry_after_s > 0
     assert gw.stats()["sessions_shed"] == 1

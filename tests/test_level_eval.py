@@ -12,7 +12,10 @@ from repro.core.garble import garble, encode_inputs, const_labels, decode_output
 from repro.core.netlist import OP_INV
 from repro.kernels.halfgate import ref as HG
 from repro.kernels.level_eval import ref as LE
-from repro.kernels.level_eval.level_eval import eval_level_pallas
+from repro.kernels.level_eval.level_eval import (
+    eval_level_pallas,
+    garble_level_pallas,
+)
 
 
 @pytest.mark.parametrize("g", [5, 128, 3000])
@@ -25,8 +28,22 @@ def test_fused_matches_oracle(g):
     ops = jax.random.randint(ks[4], (g,), 0, 3).astype(jnp.uint32)
     tw = jnp.arange(g, dtype=jnp.uint32)
     want = LE.eval_level(ops, a, b, tg, te, tw)
-    got = eval_level_pallas(ops, a, b, tg, te, tw, interpret=True)
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    got = eval_level_pallas(ops, a.T, b.T, tg.T, te.T, tw, interpret=True)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got).T)
+
+
+@pytest.mark.parametrize("g", [5, 3000])
+def test_fused_garble_matches_oracle(g):
+    ks = jax.random.split(jax.random.PRNGKey(100 + g), 4)
+    a0 = jax.random.bits(ks[0], (g, 4), dtype=jnp.uint32)
+    b0 = jax.random.bits(ks[1], (g, 4), dtype=jnp.uint32)
+    r = jax.random.bits(ks[2], (g, 4), dtype=jnp.uint32) | jnp.uint32(1)
+    ops = jax.random.randint(ks[3], (g,), 0, 4).astype(jnp.uint32)
+    tw = jnp.arange(g, dtype=jnp.uint32)
+    want = LE.garble_level(ops, a0, b0, r, tw)
+    got = garble_level_pallas(ops, a0.T, b0.T, r.T, tw, interpret=True)
+    for w, o in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(o).T)
 
 
 def test_fused_level_evaluates_circuit(rng):
@@ -64,8 +81,9 @@ def test_fused_level_evaluates_circuit(rng):
         tg = jnp.asarray(tables[slots, 0])
         te = jnp.asarray(tables[slots, 1])
         tw = jnp.asarray(slots, jnp.uint32)
-        out = eval_level_pallas(ops, a, b, tg, te, tw, interpret=True)
-        wires[net.out[lvl]] = np.asarray(out)
+        out = eval_level_pallas(ops, a.T, b.T, tg.T, te.T, tw,
+                                interpret=True)
+        wires[net.out[lvl]] = np.asarray(out).T
     out_lab = jnp.asarray(wires[net.outputs])[None]
     got_bits = decode_outputs(gc, out_lab)[0]
     got = sum(int(x) << i for i, x in enumerate(got_bits))

@@ -90,6 +90,24 @@ def test_layernorm_offload_matches_full(rng):
     assert 0.30 < reduction < 0.65, reduction
 
 
+def test_layernorm_offload_wide_rows(rng):
+    """Rows wider than 2^(frac+1), where round(2^frac / n) is 0: the
+    offload centres exactly (n·x − Σx) and divides by n in the circuit
+    (BERT-base's n=768 at frac=7 is this case)."""
+    n = 96
+    x = rng.normal(0, 1, (2, n))
+    gamma = rng.normal(1, 0.1, n)
+    beta = rng.normal(0, 0.1, n)
+    mu = x.mean(1, keepdims=True)
+    sd = np.sqrt(((x - mu) ** 2).mean(1, keepdims=True))
+    want = (x - mu) / sd * gamma + beta
+    Pr = _proto(frac=5, seed=1)
+    assert n > 2 ** (Pr.frac + 1)
+    c, s = SS.share(rng, SS.encode_fx(x, Pr.frac, Pr.t), Pr.t)
+    oc, os_ = Pr.layernorm(c, s, gamma, beta, in_scale=Pr.frac)
+    assert np.abs(Pr.reveal(oc, os_) - want).max() < 0.15
+
+
 def test_comm_accounting(P):
     st = P.stats
     assert st.channel_offline.total > 0
