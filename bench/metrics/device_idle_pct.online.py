@@ -1,0 +1,8 @@
+"""Share of the traced online window in which no operation ran on the
+device: 1 - (union of device-op intervals) / window."""
+
+
+def read(ctx):
+    if ctx.window.phase != "online" or ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.trace["window_s"])

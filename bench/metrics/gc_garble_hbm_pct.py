@@ -1,0 +1,24 @@
+"""Garbling kernel's share of the HBM roofline: the bytes garbling every
+circuit of the window's bundles has to move (32 B of table written per
+AND gate, 16 B per input and output label, counted from the program's
+netlists and instances by ``bench.counts``) over the garbling kernel's
+device time, over the chip's HBM bandwidth."""
+
+from bench import counts
+from bench import trace as T
+
+#: the fused level-garbling Pallas kernel: the compiled walk names its
+#: custom call after the jitted wrapper, ``garble_level_pallas``
+KERNEL = r"^%garble_level_pallas\b"
+
+
+def read(ctx):
+    if ctx.window.phase != "offline" or ctx.trace is None or not ctx.units:
+        return None
+    t = T.op_seconds(ctx.trace, KERNEL)
+    if t <= 0:
+        return None
+    per_bundle = sum(counts.gc_bytes(w["and"], w["inputs"], w["outputs"],
+                                     w["instances"]) for w in ctx.gc_work)
+    return (100.0 * per_bundle * ctx.units / t
+            / ctx.peaks["hbm_bytes_per_s"])
