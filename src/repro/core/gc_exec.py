@@ -82,7 +82,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import obs
 from repro.core.netlist import (
     LATENCY_MAX_INSTANCES,
     LevelPlan,
@@ -382,12 +381,7 @@ class LevelExecutor:
 
     def evaluate(self, active, tables) -> jnp.ndarray:
         self.n_eval_calls += 1
-        # host-side dispatch boundary: the span must never cross into the
-        # jitted body (jit_hygiene), so it wraps the executable call only
-        with obs.span("gc_exec.evaluate",
-                      netlist=getattr(self.plan._net, "name", "") or "",
-                      instances=self.instances, impl=self.impl):
-            return self._eval(jnp.asarray(active), jnp.asarray(tables))
+        return self._eval(jnp.asarray(active), jnp.asarray(tables))
 
     # ------------------------------------------------------------------
     # garble
@@ -498,13 +492,8 @@ class LevelExecutor:
                 "keep_wires needs the full wire store: use a "
                 "compact=False plan (rows are recycled here)")
         self.n_garble_calls += 1
-        # host-side dispatch boundary (see evaluate): span stays outside
-        # the jitted walk
-        with obs.span("gc_exec.garble",
-                      netlist=getattr(self.plan._net, "name", "") or "",
-                      instances=self.instances, impl=self.impl):
-            return self._garble(jnp.asarray(src_labels), jnp.asarray(r),
-                                keep_wires=keep_wires)
+        return self._garble(jnp.asarray(src_labels), jnp.asarray(r),
+                            keep_wires=keep_wires)
 
 
 def get_executor(net: Netlist, instances: int, impl: str,

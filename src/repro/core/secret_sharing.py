@@ -13,6 +13,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 
 def encode_fx(x: np.ndarray, frac: int, t: int) -> np.ndarray:
     v = np.round(np.asarray(x, np.float64) * (1 << frac)).astype(np.int64)
@@ -46,9 +48,14 @@ def sub_mod(a, b, t):
 
 def matmul_mod(A, B, t):
     """Exact modular matmul via object dtype (sizes are protocol-small)."""
-    return np.asarray(
-        (np.asarray(A, dtype=object) @ np.asarray(B, dtype=object)) % t
-    ).astype(np.uint64)
+    a_shape, b_shape = np.shape(A), np.shape(B)
+    with obs.span("share.matmul",
+                  m=int(a_shape[0]) if len(a_shape) > 1 else 1,
+                  k=int(a_shape[-1]),
+                  n=int(b_shape[-1]) if len(b_shape) > 1 else 1):
+        return np.asarray(
+            (np.asarray(A, dtype=object) @ np.asarray(B, dtype=object)) % t
+        ).astype(np.uint64)
 
 
 def scalar_mul_mod(c, A, t):

@@ -299,6 +299,13 @@ def _distinct_nets(protocol: PiTProtocol, plan: Plan, *, n: int = 1,
     return nets, per_req
 
 
+def _fetch_tables(tables) -> np.ndarray:
+    """A garbled slab's tables as host numpy: the host blocks here until
+    the garbling walk is done, then copies them off the device."""
+    with obs.span("gc.fetch", bytes=tables.nbytes):
+        return np.asarray(tables)
+
+
 def _read_reg(regs: Dict[str, np.ndarray], ref: RegRef) -> np.ndarray:
     v = regs[ref.reg]
     if ref.cols is not None:
@@ -376,10 +383,13 @@ class _Endpoint:
 
     def _send_sim(self, tag: str, payload, phase: int) -> None:
         self._flush()
-        frame = W.encode_msg(W.KIND_SIM, tag, payload, phase=phase)
-        self.ledger.add_sim(len(frame))
-        self.ledger.record_io(True, len(frame))
-        self.transport.send(frame)
+        with obs.span("wire.send", phase=_PHASE_NAMES.get(phase, str(phase)),
+                      kind=W.KIND_SIM) as sp:
+            frame = W.encode_msg(W.KIND_SIM, tag, payload, phase=phase)
+            sp.set(bytes=len(frame))
+            self.ledger.add_sim(len(frame))
+            self.ledger.record_io(True, len(frame))
+            self.transport.send(frame)
 
     def _send_segs(self, segs: Sequence[W.Seg], phase: int) -> None:
         if not segs:
@@ -735,13 +745,17 @@ class EvaluatorEndpoint(_Endpoint):
                     with self._in_phase("hello"):
                         self._handle_hello(msg.payload)
                 elif msg.tag == "prep":
+                    ids = msg.payload["ids"]
                     with obs.span("offline", role="evaluator",
-                                  sid=self.session.sid), \
+                                  sid=self.session.sid,
+                                  bundle=int(ids[0]) if ids else -1,
+                                  n=int(msg.payload["n"])), \
                             self._in_phase("offline"):
                         self._handle_prep(msg.payload)
                 elif msg.tag == "run":
                     with obs.span("online", role="evaluator",
-                                  sid=self.session.sid), \
+                                  sid=self.session.sid,
+                                  bundle=int(msg.payload["id"])), \
                             self._in_phase("online"):
                         self._handle_run(msg.payload)
                 else:
@@ -909,10 +923,13 @@ class EvaluatorEndpoint(_Endpoint):
                 wire_b, seed, count = heads[name]
                 resid = self._expect_msg(W.KIND_SIM, f"tables-resid:{name}")
                 meta = self._expect_msg(W.KIND_SIM, f"gc-meta:{name}")
-                tables = W.unpack_tables_delta(wire_b, resid, I_tot,
-                                               net.and_count)
-                mlab = LB.stream_labels(seed, 0, count).reshape(
-                    I_tot, n_out * k, 4)
+                with obs.span("tables.unpack") as sp:
+                    tables = W.unpack_tables_delta(wire_b, resid, I_tot,
+                                                   net.and_count)
+                    sp.set(bytes=tables.nbytes)
+                with obs.span("labels.stream", n=count):
+                    mlab = LB.stream_labels(seed, 0, count).reshape(
+                        I_tot, n_out * k, 4)
                 self.ledger.add_stream(count)
                 self.ledger.add_delta_batch(len(resid))
                 slabs[name] = {
@@ -926,8 +943,10 @@ class EvaluatorEndpoint(_Endpoint):
             for name, net in nets.items():
                 I_tot = per_req[name] * n
                 n_out, xc_bits, _ = _gc_geom(net, k)
-                tables = W.unpack_tables(self._expect_seg(f"tables:{name}"),
-                                         I_tot, net.and_count)
+                wire_b = self._expect_seg(f"tables:{name}")
+                with obs.span("tables.unpack") as sp:
+                    tables = W.unpack_tables(wire_b, I_tot, net.and_count)
+                    sp.set(bytes=tables.nbytes)
                 mlab = W.unpack_labels(self._expect_seg("g-labels"),
                                        (I_tot, n_out * k))
                 meta = self._expect_msg(W.KIND_SIM, f"gc-meta:{name}")
@@ -943,52 +962,53 @@ class EvaluatorEndpoint(_Endpoint):
         new_bundles: Dict[int, Dict[str, dict]] = {}
         for bid in ids:
             parts: Dict[str, dict] = {}
-            for op in plan.ops:
-                if op.kind == "linear":
-                    x_shape = plan.read_shape(op.reads[0])
-                    r1 = W.ct_unpack(self._expect_seg("he-enc-r"), x_shape)
-                    Wmod = sh.weight_mod(op)
-                    wr = SS.matmul_mod(r1, Wmod.T, t)
-                    with sh.rng_lock:
-                        s_mask = sh.rng.integers(0, t, wr.shape,
-                                                 dtype=np.uint64)
-                    client_y = SS.sub_mod(wr, s_mask, t)
-                    resp.append(W.Seg("he-wr", W.DIR_S2C,
-                                      W.ct_pack(client_y, p._ct_bytes,
-                                                p.params.n)))
-                    parts[op.name] = {"s_mask": s_mask}
-                elif op.kind == "beaver_matmul":
-                    m, kk = plan.read_shape(op.reads[0])
-                    _, nn = plan.read_shape(op.reads[1])
-                    with sh.rng_lock:
-                        trip = SS.deal_matmul_triple(sh.rng, m, kk, nn, t)
-                    resp.append(W.Seg(
-                        "beaver", W.DIR_S2C,
-                        W.pack_u64(trip.a1) + W.pack_u64(trip.b1)
-                        + W.pack_u64(trip.c1)))
-                    parts[op.name] = {"a2": trip.a2, "b2": trip.b2,
-                                      "c2": trip.c2}
-                else:  # GC kinds
-                    I = plan.gc_instances(op)
-                    net = gc_net_for(p, op)
-                    slab = slabs[net.name]
-                    lo = slab["off"]
-                    slab["off"] = lo + I
-                    parts[op.name] = {
-                        "net": net,
-                        "tables": slab["tables"][lo: lo + I],
-                        "mlab": slab["mlab"][lo: lo + I],
-                        "perm": slab["perm"][lo: lo + I],
-                        "cw": slab["cw"],
-                        "clab": slab["clab"][lo: lo + I],
-                    }
-                    if op.kind == "layernorm" and p.pcfg.layernorm_offload:
-                        I_ln, nn = op.shape
-                        self._expect_seg("he-ln-r")
-                        self._expect_seg("he-enc-centered")
+            with obs.span("he.offline", bundle=bid):
+                for op in plan.ops:
+                    if op.kind == "linear":
+                        x_shape = plan.read_shape(op.reads[0])
+                        r1 = W.ct_unpack(self._expect_seg("he-enc-r"), x_shape)
+                        Wmod = sh.weight_mod(op)
+                        wr = SS.matmul_mod(r1, Wmod.T, t)
                         with sh.rng_lock:
-                            parts[op.name]["he_mask"] = sh.rng.integers(
-                                0, t, I_ln, dtype=np.uint64)
+                            s_mask = sh.rng.integers(0, t, wr.shape,
+                                                     dtype=np.uint64)
+                        client_y = SS.sub_mod(wr, s_mask, t)
+                        resp.append(W.Seg("he-wr", W.DIR_S2C,
+                                          W.ct_pack(client_y, p._ct_bytes,
+                                                    p.params.n)))
+                        parts[op.name] = {"s_mask": s_mask}
+                    elif op.kind == "beaver_matmul":
+                        m, kk = plan.read_shape(op.reads[0])
+                        _, nn = plan.read_shape(op.reads[1])
+                        with sh.rng_lock:
+                            trip = SS.deal_matmul_triple(sh.rng, m, kk, nn, t)
+                        resp.append(W.Seg(
+                            "beaver", W.DIR_S2C,
+                            W.pack_u64(trip.a1) + W.pack_u64(trip.b1)
+                            + W.pack_u64(trip.c1)))
+                        parts[op.name] = {"a2": trip.a2, "b2": trip.b2,
+                                          "c2": trip.c2}
+                    else:  # GC kinds
+                        I = plan.gc_instances(op)
+                        net = gc_net_for(p, op)
+                        slab = slabs[net.name]
+                        lo = slab["off"]
+                        slab["off"] = lo + I
+                        parts[op.name] = {
+                            "net": net,
+                            "tables": slab["tables"][lo: lo + I],
+                            "mlab": slab["mlab"][lo: lo + I],
+                            "perm": slab["perm"][lo: lo + I],
+                            "cw": slab["cw"],
+                            "clab": slab["clab"][lo: lo + I],
+                        }
+                        if op.kind == "layernorm" and p.pcfg.layernorm_offload:
+                            I_ln, nn = op.shape
+                            self._expect_seg("he-ln-r")
+                            self._expect_seg("he-enc-centered")
+                            with sh.rng_lock:
+                                parts[op.name]["he_mask"] = sh.rng.integers(
+                                    0, t, I_ln, dtype=np.uint64)
             new_bundles[bid] = parts
         self._send_segs(resp, W.PHASE_OFFLINE)
         with sess.lock:
@@ -1100,10 +1120,13 @@ class EvaluatorEndpoint(_Endpoint):
         n_out, xc_bits, n_e = _gc_geom(net, k)
         I = xs.shape[0]
 
-        e_bits = bits_of(xs, k, t)
-        if raw_e is not None:
-            rv = np.mod(np.asarray(raw_e, np.int64), 1 << k).astype(np.uint64)
-            e_bits = np.concatenate([e_bits, bits_of(rv, k, 1 << k)], axis=1)
+        with obs.span("gc.encode", n=I * n_e):
+            e_bits = bits_of(xs, k, t)
+            if raw_e is not None:
+                rv = np.mod(np.asarray(raw_e, np.int64),
+                            1 << k).astype(np.uint64)
+                e_bits = np.concatenate([e_bits, bits_of(rv, k, 1 << k)],
+                                        axis=1)
         assert e_bits.shape == (I, n_e)
         if self.wire_version >= 2:
             # real IKNP extension. One-time base OT, lazily at the
@@ -1115,21 +1138,28 @@ class EvaluatorEndpoint(_Endpoint):
             g_lab_data = None
             iknp = sess.iknp
             if iknp is None:
-                with sh.rng_lock:
-                    iknp = OT.IknpReceiver(sh.rng)
-                self._send_segs([W.Seg("ot-base", W.DIR_S2C,
-                                       iknp.base_msg_a())], W.PHASE_ONLINE)
+                with obs.span("ot.base"):
+                    with sh.rng_lock:
+                        iknp = OT.IknpReceiver(sh.rng)
+                    base_a = iknp.base_msg_a()
+                self._send_segs([W.Seg("ot-base", W.DIR_S2C, base_a)],
+                                W.PHASE_ONLINE)
                 g_lab_data = self._expect_seg("g-labels")
-                iknp.absorb_base_b(self._expect_seg("ot-base"))
+                base_b = self._expect_seg("ot-base")
+                with obs.span("ot.base"):
+                    iknp.absorb_base_b(base_b)
                 sess.iknp = iknp
-            u, t_cols = iknp.extend(e_bits)
+            with obs.span("ot.extend", n=I * n_e):
+                u, t_cols = iknp.extend(e_bits)
             self._send_segs([W.Seg(f"ot:{net.name}", W.DIR_C2S, u)],
                             W.PHASE_ONLINE)
             if g_lab_data is None:
                 g_lab_data = self._expect_seg("g-labels")
             g_lab = W.unpack_labels(g_lab_data, (I, xc_bits))
-            e_lab = iknp.receive(self._expect_seg(f"ot:{net.name}"),
-                                 e_bits, t_cols).reshape(I, n_e, 4)
+            ot_data = self._expect_seg(f"ot:{net.name}")
+            with obs.span("ot.receive", n=I * n_e):
+                e_lab = iknp.receive(ot_data, e_bits,
+                                     t_cols).reshape(I, n_e, 4)
         else:
             # sim-OT: the receiver's choice-derived messages (logical c2s
             # in the oracle's ledger; see core/ot.ot_labels)
@@ -1138,18 +1168,30 @@ class EvaluatorEndpoint(_Endpoint):
                             W.PHASE_ONLINE)
             g_lab = W.unpack_labels(self._expect_seg("g-labels"),
                                     (I, xc_bits))
-            e_lab = W.unpack_ot_response(self._expect_seg(f"ot:{net.name}"),
-                                         (I, n_e))
+            ot_data = self._expect_seg(f"ot:{net.name}")
+            with obs.span("ot.receive", n=I * n_e):
+                e_lab = W.unpack_ot_response(ot_data, (I, n_e))
         wire_ids = np.concatenate([
             np.asarray(net.garbler_inputs, np.int64),
             np.asarray(net.evaluator_inputs, np.int64), part["cw"]])
         labels = np.concatenate([g_lab, part["mlab"], e_lab, part["clab"]],
                                 axis=1)
-        out_lab = G.evaluate(net, jnp.asarray(part["tables"]),
-                             (wire_ids, jnp.asarray(labels)), impl=sh.impl)
-        out_bits = ((np.asarray(out_lab)[..., 0] & 1) ^ part["perm"]
-                    ).astype(np.uint8)
-        return words_from_bits(out_bits, k, t)
+        # the bundle's tables stay host numpy until here: every op uploads
+        # its band of them, with the op's labels, before the evaluation
+        with obs.span("gc.upload",
+                      bytes=part["tables"].nbytes + labels.nbytes):
+            tables_dev = jnp.asarray(part["tables"])
+            labels_dev = jnp.asarray(labels)
+        out_lab = G.evaluate(net, tables_dev, (wire_ids, labels_dev),
+                             impl=sh.impl)
+        # the host waits here for the evaluation dispatched above
+        with obs.span("gc.fetch") as sp:
+            out_lab = np.asarray(out_lab)
+            sp.set(bytes=out_lab.nbytes)
+        with obs.span("gc.decode", n=I * n_out * k):
+            out_bits = ((out_lab[..., 0] & 1) ^ part["perm"]
+                        ).astype(np.uint8)
+            return words_from_bits(out_bits, k, t)
 
     def _server_layernorm(self, op: OpSpec, part: dict, hs: np.ndarray
                           ) -> np.ndarray:
@@ -1332,16 +1374,18 @@ class GarblerEndpoint(_Endpoint):
         sh = self.shared
         if sh.plan is None:
             self.handshake()
-        with self._lock, obs.span("offline", role="garbler", bundles=n), \
-                self._in_phase("offline"):
-            return self._preprocess_locked(n)
+        with self._lock:
+            ids = [next(_bundle_ids) for _ in range(n)]
+            with obs.span("offline", role="garbler", bundle=ids[0], n=n), \
+                    self._in_phase("offline"):
+                return self._preprocess_locked(ids)
 
-    def _preprocess_locked(self, n: int) -> List[int]:
+    def _preprocess_locked(self, ids: List[int]) -> List[int]:
         sh = self.shared
         p = sh.protocol
         plan = sh.plan
         t, k = p.t, p.k
-        ids = [next(_bundle_ids) for _ in range(n)]
+        n = len(ids)
         self._send_control("prep", {"n": n, "ids": ids})
         # admission gate BEFORE any garbling: a bounded server pool sheds
         # here (BundlePoolEmpty via the CONTROL shed frame) while the
@@ -1355,92 +1399,108 @@ class GarblerEndpoint(_Endpoint):
         for name, net in nets.items():
             I_tot = per_req[name] * n
             n_out, xc_bits, _ = _gc_geom(net, k)
-            if v2c:
-                # v2: masks are drawn BEFORE garbling so the mask-wire
-                # active labels can be preset to the PRG stream — the
-                # evaluator replays the same stream from the 32-byte
-                # seed record instead of receiving raw labels, and the
-                # table batch ships delta-encoded (anchor + per-instance
-                # XOR head; the residual rides the sim sideband)
-                masks = sh.rng.integers(0, t, (I_tot, n_out),
-                                        dtype=np.uint64)
-                mask_enc = SS.sub_mod(np.zeros_like(masks), masks, t)
-                seed = LB.stream_seed(sh.rng)
-                with obs.span("garble", netlist=name, instances=I_tot):
-                    gcirc = G.garble(
-                        net, p._next_key(), I_tot, impl=sh.impl,
-                        seeded_inputs=(net.garbler_inputs[xc_bits:],
-                                       bits_of(mask_enc, k, t), seed, 0))
-                wire_b, resid = W.pack_tables_delta(gcirc.tables)
-                self._send_segs([
-                    W.Seg(f"tables:{name}", W.DIR_C2S, wire_b),
-                    W.Seg("g-labels", W.DIR_C2S,
-                          W.pack_seed_stream(seed, 0, I_tot * n_out * k)),
-                ], W.PHASE_OFFLINE)
-                self.ledger.add_stream(I_tot * n_out * k)
-                self.ledger.add_delta_batch(len(resid))
-                sims.append((f"tables-resid:{name}", resid))
-            else:
-                with obs.span("garble", netlist=name, instances=I_tot):
-                    gcirc = G.garble(net, p._next_key(), I_tot,
-                                     impl=sh.impl)
-                masks = sh.rng.integers(0, t, (I_tot, n_out),
-                                        dtype=np.uint64)
-                mask_enc = SS.sub_mod(np.zeros_like(masks), masks, t)
-                mlab = G.encode_inputs(gcirc, net.garbler_inputs[xc_bits:],
-                                       bits_of(mask_enc, k, t))
-                self._send_segs([
-                    W.Seg(f"tables:{name}", W.DIR_C2S,
-                          W.pack_tables(gcirc.tables)),
-                    W.Seg("g-labels", W.DIR_C2S, W.pack_labels(mlab)),
-                ], W.PHASE_OFFLINE)
-            cw, clab = G.const_wires_labels(gcirc)
-            meta = {
-                "perm": np.asarray(gcirc.output_perm),
-                "cw": np.asarray(cw), "clab": np.asarray(clab),
-            }
-            if v2c:
-                # defer the sideband so all slab segments coalesce into
-                # one offline frame; the evaluator pops every PROTO seg
-                # first, then the sims, in this exact order
-                sims.append((f"gc-meta:{name}", meta))
-            else:
-                self._send_sim(f"gc-meta:{name}", meta, W.PHASE_OFFLINE)
-            slabs[name] = (gcirc, masks)
-        for tag, obj in sims:
-            self._send_sim(tag, obj, W.PHASE_OFFLINE)
+            with obs.span("slab", netlist=name, instances=I_tot):
+                if v2c:
+                    # v2: masks are drawn BEFORE garbling so the mask-wire
+                    # active labels can be preset to the PRG stream — the
+                    # evaluator replays the same stream from the 32-byte
+                    # seed record instead of receiving raw labels, and the
+                    # table batch ships delta-encoded (anchor + per-instance
+                    # XOR head; the residual rides the sim sideband)
+                    masks = sh.rng.integers(0, t, (I_tot, n_out),
+                                            dtype=np.uint64)
+                    mask_enc = SS.sub_mod(np.zeros_like(masks), masks, t)
+                    seed = LB.stream_seed(sh.rng)
+                    # ends once the walk is dispatched: the host waits for
+                    # it in gc.fetch
+                    with obs.span("garble", netlist=name, instances=I_tot):
+                        gcirc = G.garble(
+                            net, p._next_key(), I_tot, impl=sh.impl,
+                            seeded_inputs=(net.garbler_inputs[xc_bits:],
+                                           bits_of(mask_enc, k, t), seed, 0))
+                    tables = _fetch_tables(gcirc.tables)
+                    with obs.span("tables.pack", bytes=tables.nbytes):
+                        wire_b, resid = W.pack_tables_delta(tables)
+                        stream = W.pack_seed_stream(seed, 0,
+                                                    I_tot * n_out * k)
+                    self._send_segs([
+                        W.Seg(f"tables:{name}", W.DIR_C2S, wire_b),
+                        W.Seg("g-labels", W.DIR_C2S, stream),
+                    ], W.PHASE_OFFLINE)
+                    self.ledger.add_stream(I_tot * n_out * k)
+                    self.ledger.add_delta_batch(len(resid))
+                    sims.append((f"tables-resid:{name}", resid))
+                else:
+                    # ends on dispatch, as above
+                    with obs.span("garble", netlist=name, instances=I_tot):
+                        gcirc = G.garble(net, p._next_key(), I_tot,
+                                         impl=sh.impl)
+                    masks = sh.rng.integers(0, t, (I_tot, n_out),
+                                            dtype=np.uint64)
+                    mask_enc = SS.sub_mod(np.zeros_like(masks), masks, t)
+                    mlab = G.encode_inputs(gcirc,
+                                           net.garbler_inputs[xc_bits:],
+                                           bits_of(mask_enc, k, t))
+                    tables = _fetch_tables(gcirc.tables)
+                    with obs.span("tables.pack", bytes=tables.nbytes):
+                        wire_t = W.pack_tables(tables)
+                        wire_l = W.pack_labels(mlab)
+                    self._send_segs([
+                        W.Seg(f"tables:{name}", W.DIR_C2S, wire_t),
+                        W.Seg("g-labels", W.DIR_C2S, wire_l),
+                    ], W.PHASE_OFFLINE)
+                cw, clab = G.const_wires_labels(gcirc)
+                meta = {
+                    "perm": np.asarray(gcirc.output_perm),
+                    "cw": np.asarray(cw), "clab": np.asarray(clab),
+                }
+                if v2c:
+                    # defer the sideband so all slab segments coalesce into
+                    # one offline frame; the evaluator pops every PROTO seg
+                    # first, then the sims, in this exact order
+                    sims.append((f"gc-meta:{name}", meta))
+                else:
+                    self._send_sim(f"gc-meta:{name}", meta, W.PHASE_OFFLINE)
+                slabs[name] = (gcirc, masks)
+        if sims:
+            # the coalesced table frame goes out with the first sim
+            with obs.span("tables.send", frames=len(sims) + 1):
+                for tag, obj in sims:
+                    self._send_sim(tag, obj, W.PHASE_OFFLINE)
 
         offsets = {name: 0 for name in nets}
         new_bundles: Dict[int, Dict[str, dict]] = {}
         for bid in ids:
             parts: Dict[str, dict] = {}
             segs: List[W.Seg] = []
-            for op in plan.ops:
-                if op.kind == "linear":
-                    x_shape = plan.read_shape(op.reads[0])
-                    r1 = sh.rng.integers(0, t, x_shape, dtype=np.uint64)
-                    segs.append(W.Seg("he-enc-r", W.DIR_C2S,
-                                      W.ct_pack(r1, p._ct_bytes, p.params.n)))
-                    parts[op.name] = {"r1": r1}
-                elif op.kind == "beaver_matmul":
-                    parts[op.name] = {}
-                else:  # GC kinds
-                    I = plan.gc_instances(op)
-                    net = gc_net_for(p, op)
-                    lo = offsets[net.name]
-                    offsets[net.name] = lo + I
-                    gcirc, masks = slabs[net.name]
-                    parts[op.name] = {
-                        "gc": G.slice_instances(gcirc, lo, lo + I),
-                        "masks": masks[lo: lo + I],
-                    }
-                    if op.kind == "layernorm" and p.pcfg.layernorm_offload:
-                        I_ln, nn = op.shape
-                        blocks = W.ct_blocks(I_ln * nn, p.params.n)
-                        segs.append(W.Seg("he-ln-r", W.DIR_C2S,
-                                          bytes(blocks * p._ct_bytes)))
-                        segs.append(W.Seg("he-enc-centered", W.DIR_C2S,
-                                          bytes(I_ln * p._ct_bytes)))
+            with obs.span("he.offline", bundle=bid):
+                for op in plan.ops:
+                    if op.kind == "linear":
+                        x_shape = plan.read_shape(op.reads[0])
+                        r1 = sh.rng.integers(0, t, x_shape, dtype=np.uint64)
+                        segs.append(W.Seg(
+                            "he-enc-r", W.DIR_C2S,
+                            W.ct_pack(r1, p._ct_bytes, p.params.n)))
+                        parts[op.name] = {"r1": r1}
+                    elif op.kind == "beaver_matmul":
+                        parts[op.name] = {}
+                    else:  # GC kinds
+                        I = plan.gc_instances(op)
+                        net = gc_net_for(p, op)
+                        lo = offsets[net.name]
+                        offsets[net.name] = lo + I
+                        gcirc, masks = slabs[net.name]
+                        parts[op.name] = {
+                            "gc": G.slice_instances(gcirc, lo, lo + I),
+                            "masks": masks[lo: lo + I],
+                        }
+                        if op.kind == "layernorm" and p.pcfg.layernorm_offload:
+                            I_ln, nn = op.shape
+                            blocks = W.ct_blocks(I_ln * nn, p.params.n)
+                            segs.append(W.Seg("he-ln-r", W.DIR_C2S,
+                                              bytes(blocks * p._ct_bytes)))
+                            segs.append(W.Seg("he-enc-centered", W.DIR_C2S,
+                                              bytes(I_ln * p._ct_bytes)))
             self._send_segs(segs, W.PHASE_OFFLINE)
             new_bundles[bid] = parts
 
@@ -1492,7 +1552,7 @@ class GarblerEndpoint(_Endpoint):
             if parts is None:
                 raise NetProtocolError(
                     f"bundle {bundle_id} unknown or already consumed")
-            with obs.span("online", role="garbler", bundle_id=bundle_id), \
+            with obs.span("online", role="garbler", bundle=bundle_id), \
                     self._in_phase("online"):
                 return self._run_locked(x, bundle_id, parts)
 
@@ -1570,9 +1630,12 @@ class GarblerEndpoint(_Endpoint):
         net = gcirc.net
         n_out, xc_bits, n_e = _gc_geom(net, k)
         I = xc.shape[0]
-        g_lab = G.encode_inputs(gcirc, net.garbler_inputs[:xc_bits],
-                                bits_of(xc, k, t))
-        self._send_segs([W.Seg("g-labels", W.DIR_C2S, W.pack_labels(g_lab))],
+        # the labels are fetched off the device in pack_labels
+        with obs.span("gc.encode", n=I * xc_bits):
+            g_lab = G.encode_inputs(gcirc, net.garbler_inputs[:xc_bits],
+                                    bits_of(xc, k, t))
+            g_lab_b = W.pack_labels(g_lab)
+        self._send_segs([W.Seg("g-labels", W.DIR_C2S, g_lab_b)],
                         W.PHASE_ONLINE)
         if self.wire_version >= 2:
             # IKNP sender leg: answer the one-time base OT if this is
@@ -1580,25 +1643,27 @@ class GarblerEndpoint(_Endpoint):
             # every evaluator wire under the extension-matrix hash
             if sh.iknp is None:
                 a_data = self._expect_seg("ot-base")
-                with sh.lock:
+                with obs.span("ot.base"), sh.lock:
                     snd = OT.IknpSender(sh.rng)
                     b_data = snd.base_msg_b(a_data)
                     sh.iknp = snd
                 self._send_segs([W.Seg("ot-base", W.DIR_C2S, b_data)],
                                 W.PHASE_ONLINE)
             u_data = self._expect_seg(f"ot:{net.name}")
-            e_zero = G.input_zeros(gcirc, net.evaluator_inputs)
-            y = sh.iknp.respond(u_data, I * n_e, np.asarray(e_zero),
-                                np.asarray(gcirc.r)[:, None, :])
+            with obs.span("ot.respond", n=I * n_e):
+                e_zero = G.input_zeros(gcirc, net.evaluator_inputs)
+                y = sh.iknp.respond(u_data, I * n_e, np.asarray(e_zero),
+                                    np.asarray(gcirc.r)[:, None, :])
             self._send_segs([W.Seg(f"ot:{net.name}", W.DIR_S2C, y)],
                             W.PHASE_ONLINE)
         else:
             choice = W.unpack_ot_request(self._expect_seg(f"ot:{net.name}"),
                                          (I, n_e))
-            e_zero = G.input_zeros(gcirc, net.evaluator_inputs)
-            e_lab = OT.choose_labels(e_zero, gcirc.r[:, None, :], choice)
-            self._send_segs([W.Seg(f"ot:{net.name}", W.DIR_S2C,
-                                   W.pack_ot_response(e_lab))],
+            with obs.span("ot.respond", n=I * n_e):
+                e_zero = G.input_zeros(gcirc, net.evaluator_inputs)
+                e_lab = OT.choose_labels(e_zero, gcirc.r[:, None, :], choice)
+                y = W.pack_ot_response(e_lab)
+            self._send_segs([W.Seg(f"ot:{net.name}", W.DIR_S2C, y)],
                             W.PHASE_ONLINE)
         return part["masks"]
 

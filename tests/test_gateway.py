@@ -14,6 +14,7 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.config import PrivacyConfig
 from repro.core.engine import PrivateTransformer, random_weights
 from repro.net import InProcPipe, TcpListener
@@ -93,6 +94,18 @@ def test_accept_loop_max_accepts():
 
 
 def test_gateway_four_tcp_sessions_bit_identical():
+    tracer = obs.enable()
+    try:
+        _four_tcp_sessions()
+    finally:
+        obs.disable()
+    # the evaluator's table codec sits under the gateway's prep span
+    paths = {sp.path for sp in tracer.finished_spans()}
+    assert "offline/gateway.prep/tables.unpack" in paths, sorted(paths)
+    assert "offline/gateway.prep/he.offline/share.matmul" in paths
+
+
+def _four_tcp_sessions():
     model = _model(seed=11)
     gw = PitGateway(model, S, impl="ref", max_sessions=8, pool_cap=4)
     lst = TcpListener()

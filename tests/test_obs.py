@@ -246,8 +246,64 @@ def test_trace_reconciles_with_wire_ledger_tcp(tracer, tmp_path,
     paths = {sp.path for sp in tracer.finished_spans()}
     assert "offline" in paths and "online" in paths
     assert any(p.startswith("online/op:") for p in paths), sorted(paths)
-    assert "offline/garble" in paths  # client-side garbling under offline
+    # client-side garbling, one slab per netlist under offline
+    assert "offline/slab/garble" in paths
     assert any(p.startswith("online/wire.") for p in paths)
+
+    # the host work inside both parties' legs, each on its party's
+    # thread (the garbler drives from this thread, the evaluator serves
+    # from its own) under the right parent
+    me = threading.get_ident()
+    spans = tracer.finished_spans()
+    garbler = {sp.path for sp in spans if sp._tid == me}
+    evaluator = {sp.path for sp in spans if sp._tid != me}
+    for path in ("offline/slab", "offline/slab/gc.fetch",
+                 "offline/slab/tables.pack", "offline/he.offline",
+                 "online/op:trunc/gc.encode", "online/op:trunc/ot.respond",
+                 "online/op:beaver_matmul/share.matmul"):
+        assert path in garbler, (path, sorted(garbler))
+    for path in ("offline/tables.unpack", "offline/he.offline",
+                 "offline/he.offline/share.matmul",
+                 "online/op:linear/share.matmul",
+                 "online/op:beaver_matmul/share.matmul",
+                 "online/op:trunc/gc.encode", "online/op:trunc/ot.receive",
+                 "online/op:trunc/gc.upload", "online/op:trunc/gc.fetch",
+                 "online/op:trunc/gc.decode"):
+        assert path in evaluator, (path, sorted(evaluator))
+    v2_only = {"offline/tables.send": garbler,
+               "offline/labels.stream": evaluator,
+               "online/op:gc_apply/ot.extend": evaluator}
+    for path, side in v2_only.items():
+        assert (path in side) == (wire_version == 2), path
+    assert any(p.endswith("/ot.base") for p in garbler) == (wire_version == 2)
+    assert any(p.endswith("/ot.base") for p in evaluator) == \
+        (wire_version == 2)
+    assert not any("gc_exec." in p for p in garbler | evaluator)
+    roots = [sp for sp in spans if sp.path in ("offline", "online")]
+    assert len(roots) == 4
+    assert all(isinstance(sp.attrs["bundle"], int) for sp in roots)
+
+    # gc.upload carries each GC op's tables (32 B per AND) and labels
+    # (16 B per input and constant wire), counted here from the netlists
+    from repro.core.plan import GC_KINDS
+    from repro.core.session import gc_net_for
+    plan, prot = srv.shared.plan, srv.shared.protocol
+    want = []
+    for op in plan.ops:
+        if op.kind in GC_KINDS:
+            net = gc_net_for(prot, op)
+            wires = (len(net.garbler_inputs) + len(net.evaluator_inputs)
+                     + len(net.const_bits))
+            want.append(plan.gc_instances(op)
+                        * (32 * max(net.and_count, 1) + 16 * wires))
+    uploads = sorted((sp for sp in spans if sp.name == "gc.upload"),
+                     key=lambda sp: sp.t0_ns)
+    assert [sp.attrs["bytes"] for sp in uploads] == want
+
+    # every attribute is a plain scalar, never an array or numpy scalar
+    for sp in spans:
+        for k, v in sp.attrs.items():
+            assert type(v) in (int, float, str, bool), (sp.path, k, v)
 
     # the exported artifact passes the CI validator end to end
     out = tmp_path / f"recon_v{wire_version}.json"
