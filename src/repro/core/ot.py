@@ -161,32 +161,47 @@ class _FixedBase:
         return acc
 
 
-def _prg_bits(seed: bytes, word_offset: int, n: int) -> np.ndarray:
-    """n pseudorandom bits (uint8) from a 16-byte seed at a 64-bit-word
-    offset (one Philox counter block = four 64-bit words)."""
-    nw = -(-n // 64)
-    bg = np.random.Philox(key=int.from_bytes(seed, "little"))
-    if word_offset:
-        bg.advance(word_offset // 4)
-    skip = word_offset % 4
-    words = np.random.Generator(bg).integers(
-        0, 1 << 64, size=nw + skip, dtype=np.uint64, endpoint=False)
-    return np.unpackbits(words[skip:].view(np.uint8),
-                         bitorder="little")[:n]
+def _streams(seeds) -> list:
+    """One counter-mode Philox stream per 16-byte seed, kept for the
+    session: each batch draws its rows' words where the last one ended."""
+    return [np.random.Philox(key=int.from_bytes(k, "little")) for k in seeds]
 
 
-def _pack_cols(bits: np.ndarray) -> np.ndarray:
-    """Bit matrix (KAPPA, n) -> per-OT 128-bit columns (n, 4) uint32."""
-    cols = np.packbits(np.ascontiguousarray(bits.T), axis=1,
-                       bitorder="little")
+def _draw_rows(streams, nw: int) -> np.ndarray:
+    """(len(streams), nw) uint64: the next nw words of every stream. Bit j
+    of a row (little-endian) belongs to OT j of the batch."""
+    return np.stack([g.random_raw(nw) for g in streams])
+
+
+def _transpose8(x: np.ndarray) -> np.ndarray:
+    """Transpose each uint64 as an 8x8 bit block, in place: bit 8r+c <->
+    bit 8c+r (three delta swaps)."""
+    t = np.empty_like(x)
+    for mask, sh in ((0x00AA00AA00AA00AA, 7), (0x0000CCCC0000CCCC, 14),
+                     (0x00000000F0F0F0F0, 28)):
+        sh = np.uint64(sh)
+        np.right_shift(x, sh, out=t)
+        t ^= x
+        t &= np.uint64(mask)
+        x ^= t
+        t <<= sh
+        x ^= t
+    return x
+
+
+def _cols(rows: np.ndarray, n: int) -> np.ndarray:
+    """(KAPPA, nw) uint64 rows -> per-OT 128-bit columns (n, 4) uint32.
+
+    Byte b of row 8c+k holds bit k of column byte c for OTs 8b..8b+7;
+    gathering those eight row bytes into one word makes an 8x8 bit block
+    whose transpose is the eight OTs' column byte c."""
+    nb = 8 * rows.shape[1]  # bytes per row
+    rb = rows.view(np.uint8).reshape(KAPPA // 8, 8, nb)  # (c, k, b)
+    blocks = np.ascontiguousarray(rb.transpose(2, 0, 1))  # (b, c, k)
+    blocks = _transpose8(blocks.view(np.uint64)).view(np.uint8)
+    cols = blocks.reshape(-1, KAPPA // 8, 8).transpose(0, 2, 1)  # (b, m, c)
+    cols = cols.reshape(-1, KAPPA // 8)[:n]
     return np.ascontiguousarray(cols).view(np.uint32)
-
-
-def _unpack_cols(cols: np.ndarray) -> np.ndarray:
-    """(n, 4) uint32 columns -> bit matrix (KAPPA, n) uint8."""
-    rows = np.unpackbits(np.ascontiguousarray(cols).view(np.uint8),
-                         axis=1, bitorder="little")
-    return np.ascontiguousarray(rows.T)
 
 
 def _crh(blocks: np.ndarray, tweak0: int) -> np.ndarray:
@@ -203,8 +218,7 @@ class IknpReceiver:
 
     def __init__(self, rng: np.random.Generator):
         self._a = int.from_bytes(rng.bytes(_EXP_BYTES), "little") | 1
-        self._k0 = self._k1 = None
-        self._word_off = 0
+        self._t_streams = self._v_streams = None
         self._tweak = 0
 
     def base_msg_a(self) -> bytes:
@@ -225,19 +239,18 @@ class IknpReceiver:
             Ba = pow(B, a, p)
             k0.append(_h_group(Ba))
             k1.append(_h_group(Ba * A_neg_a % p))
-        self._k0, self._k1 = k0, k1
+        self._t_streams, self._v_streams = _streams(k0), _streams(k1)
 
     def extend(self, choice_bits: np.ndarray):
         """Choice bits -> (u column matrix bytes, private t columns)."""
         x = np.asarray(choice_bits, np.uint8).reshape(-1)
         n = x.size
-        t_rows = np.stack([_prg_bits(k, self._word_off, n)
-                           for k in self._k0])
-        v_rows = np.stack([_prg_bits(k, self._word_off, n)
-                           for k in self._k1])
-        self._word_off += -(-n // 64)
-        u_rows = t_rows ^ v_rows ^ x[None, :]
-        return _pack_cols(u_rows).tobytes(), _pack_cols(t_rows)
+        nw = -(-n // 64)
+        xw = np.zeros(8 * nw, np.uint8)  # choice bits packed like a row
+        xw[:-(-n // 8)] = np.packbits(x, bitorder="little")
+        t_rows = _draw_rows(self._t_streams, nw)
+        u_rows = t_rows ^ _draw_rows(self._v_streams, nw) ^ xw.view(np.uint64)
+        return _cols(u_rows, n).tobytes(), _cols(t_rows, n)
 
     def receive(self, y_data: bytes, choice_bits: np.ndarray,
                 t_cols: np.ndarray) -> np.ndarray:
@@ -260,9 +273,8 @@ class IknpSender:
         self._b = [int.from_bytes(rng.bytes(_EXP_BYTES), "little") | 1
                    for _ in range(KAPPA)]
         self._A = None
-        self._ks = None
+        self._g_streams = None
         self._s_block = None
-        self._word_off = 0
         self._tweak = 0
 
     def base_msg_b(self, a_data: bytes) -> bytes:
@@ -280,9 +292,9 @@ class IknpSender:
                 B = B * self._A % p
             out += B.to_bytes(BASE_OT_MSG_BYTES, "big")
             ks.append(_h_group(fb_a.pow(self._b[i])))
-        self._ks = ks
-        self._s_block = _pack_cols(
-            self._s_bits[:, None].astype(np.uint8)).reshape(4)
+        self._g_streams = _streams(ks)
+        self._s_block = np.packbits(self._s_bits,
+                                    bitorder="little").view(np.uint32)
         return bytes(out)
 
     def respond(self, u_data: bytes, n: int, zero_labels,
@@ -292,12 +304,10 @@ class IknpSender:
         ``zero_labels``: (..., 4) with n leading elements; ``r``
         broadcastable FreeXOR offset. Output: n × (y0, y1) 32-byte pairs.
         """
-        u_rows = _unpack_cols(np.frombuffer(u_data, np.uint32).reshape(n, 4))
-        g_rows = np.stack([
-            _prg_bits(self._ks[i], self._word_off, n) for i in range(KAPPA)])
-        self._word_off += -(-n // 64)
-        q_rows = g_rows ^ (self._s_bits[:, None] & u_rows)
-        q_cols = _pack_cols(q_rows)
+        # q_j = g_j ^ (s & u_j) column by column: u stays as received
+        u_cols = np.frombuffer(u_data, np.uint32).reshape(n, 4)
+        g_cols = _cols(_draw_rows(self._g_streams, -(-n // 64)), n)
+        q_cols = g_cols ^ (u_cols & self._s_block[None, :])
         z = np.asarray(zero_labels, np.uint32)
         one = z ^ np.broadcast_to(np.asarray(r, np.uint32), z.shape)
         lab0 = z.reshape(n, 4)
