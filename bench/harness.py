@@ -2,21 +2,25 @@
 
 A cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<traffic>.json``); each metric is a reader in
-``bench/metrics/<name>.py``. Everything is found by the names that
-``BENCHMARK.json`` gives, so a cell or a metric is added with files and
-entries alone.
+``bench/metrics/<name>.py``. A configuration names its architecture's
+family (``bench/families/<family>.py``), which holds its weights, plain
+reference, FLOP count and the builder of the model the program serves.
+Everything is found by the names that ``BENCHMARK.json`` and the
+configuration give, so a cell, a metric or an architecture is added with
+files and entries alone.
 
 One run is one process. The gateway listens on a loopback TCP port and
 the client connects to it, both as threads of this process. Set-up builds
 the system from the seed, makes the bundles the traffic needs and warms
 every shape the window uses; the window then drives the client for
-``--seconds``; after it, the outputs are compared with the plain reference
-in ``bench/model.py``.
+``--seconds``; after it, the outputs are compared with the family's plain
+reference.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib.util
 import json
 import shutil
@@ -26,11 +30,12 @@ import threading
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import compile_clock, counts
+from bench import compile_clock
 from bench import model as M
 from bench import trace as T
 
@@ -62,6 +67,7 @@ class Cell:
     chips: int
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: ModuleType
 
 
 def load_benchmark(root: Path) -> dict:
@@ -74,6 +80,21 @@ def traffic_file(root: Path, name: str) -> Path:
 
 def metric_file(root: Path, name: str) -> Path:
     return root / "bench" / "metrics" / f"{name}.py"
+
+
+def family_file(root: Path, name: str) -> Path:
+    return root / "bench" / "families" / f"{name}.py"
+
+
+def _load_module(path: Path, prefix: str, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered before it runs, as an import would be, so that code which
+    # looks its module up (a dataclass, pickling) finds it
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 #: every key a traffic file of each kind may hold, all of them honoured:
@@ -109,25 +130,38 @@ def load_cell(root: Path, workload: str) -> Cell:
                        f"(has {sorted(cells)})")
     w = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / conf["file"]).read_text())
+    if "family" not in config:
+        raise ValueError(
+            f"{conf['file']} names no family: give it \"family\": "
+            f"\"<name>\" for the file {family_file(root, '<name>')}")
+    family = load_family(root, config["family"])
+    family.check(config)
     e2e = [m for m in bench["end_to_end"]
            if workload in m.get("workloads", [workload])]
     e2e_names = {m["name"] for m in e2e}
     per = [m for m in bench["per_layer"]
            if workload in m.get("workloads", [workload]
                                 if m["moves"] in e2e_names else [])]
-    return Cell(name=workload,
-                config=json.loads((root / conf["file"]).read_text()),
+    return Cell(name=workload, config=config,
                 traffic=load_traffic(root, w["traffic"]),
-                chips=int(w["chips"]), end_to_end=e2e, per_layer=per)
+                chips=int(w["chips"]), end_to_end=e2e, per_layer=per,
+                family=family)
 
 
 def load_reader(root: Path, name: str) -> Callable:
-    path = metric_file(root, name)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(metric_file(root, name), "bench_metric_", name).read
+
+
+def load_family(root: Path, name: str) -> ModuleType:
+    """The family module ``bench/families/<name>.py``: ``check``,
+    ``describe``, ``make_weights``, ``reference``, ``flops``, ``build`` and,
+    where its client input is not ``bench.model.make_input``'s, its own
+    ``make_input``."""
+    path = family_file(root, name)
+    if not path.is_file():
+        raise FileNotFoundError(f"family {name!r} has no file {path}")
+    return _load_module(path, "bench_family_", name)
 
 
 # ---------------------------------------------------------------------------
@@ -145,11 +179,10 @@ class System:
     run, the host oracle ``"ref"`` in the CPU tests.
     """
 
-    def __init__(self, cfg: dict, seq_len: int, seed: int, *,
-                 gateway_pool: int, frac_bits: Optional[int] = None,
-                 impl: str = "auto"):
+    def __init__(self, cfg: dict, family: ModuleType, seq_len: int,
+                 seed: int, *, gateway_pool: int,
+                 frac_bits: Optional[int] = None, impl: str = "auto"):
         from repro.config import PrivacyConfig
-        from repro.core.engine import BertWeights, PrivateTransformer
         from repro.net import TcpListener
         from repro.serve import PitGateway, gateway_client
 
@@ -161,13 +194,10 @@ class System:
             layernorm_offload=priv["layernorm_offload"])
         s_model, s_gw, s_client = M.seed_words(seed, 3)
         t = time.perf_counter()
-        self.weights = M.make_weights(cfg, seed)
+        self.weights = family.make_weights(cfg, seed)
         self.weights_s = time.perf_counter() - t
         t = time.perf_counter()
-        self.model = PrivateTransformer(
-            self.pcfg, cfg["hidden_size"], cfg["num_attention_heads"],
-            cfg["intermediate_size"],
-            [BertWeights(**w) for w in self.weights], seed=s_model)
+        self.model = family.build(self.pcfg, cfg, self.weights, s_model)
         self.gateway = PitGateway(self.model, seq_len, seed=s_gw, impl=impl,
                                   max_sessions=1, pool_cap=gateway_pool)
         self.listener = TcpListener()
@@ -283,20 +313,20 @@ def run_cell(root: Path, cell: Cell, seed: int, seconds: float,
     result object that ``run.py`` prints as its last line."""
     from repro import obs
 
-    cfg, traffic = cell.config, cell.traffic
+    cfg, traffic, family = cell.config, cell.traffic, cell.family
     phase = traffic["kind"]
     seq_len = int(traffic["seq_len"])
+    inputs = functools.partial(
+        getattr(family, "make_input", M.make_input), cfg, seq_len, seed)
     pool = int(cfg["pool_cap"])
     if phase == "online" and trace:
         pool = min(pool, int(traffic["trace_pool"]))
     peaks = peaks_for(device["kind"]) if device["platform"] == "tpu" else {}
     clock = compile_clock.CompileClock()
     obs.enable()
-    log(f"cell {cell.name}: {cfg['name']} d={cfg['hidden_size']} "
-        f"heads={cfg['num_attention_heads']} d_ff="
-        f"{cfg['intermediate_size']} layers={cfg['num_hidden_layers']} "
+    log(f"cell {cell.name}: {cfg['name']} {family.describe(cfg)} "
         f"S={seq_len}; traffic {phase}; seed {seed}; pool {pool}")
-    system = System(cfg, seq_len, seed, gateway_pool=pool + 1,
+    system = System(cfg, family, seq_len, seed, gateway_pool=pool + 1,
                     frac_bits=frac_bits, impl=impl)
     eng = system.client
     win = Window(phase=phase)
@@ -313,7 +343,7 @@ def run_cell(root: Path, cell: Cell, seed: int, seconds: float,
             f"(netlists, plans, compiles, one bundle)")
         t = time.perf_counter()
         if phase == "online":
-            _warm_online(eng, cfg, seq_len, seed, pool)
+            _warm_online(eng, inputs, pool)
         log(f"set-up: warm-up and pool fill {time.perf_counter() - t:.3f}s")
         gc_work = system.gc_work()
         _log_setup(obs.current().finished_spans(), clock)
@@ -325,8 +355,8 @@ def run_cell(root: Path, cell: Cell, seed: int, seconds: float,
         tdir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
         try:
             win, anchor_ns, setup_s = _window(
-                system, cfg, traffic, seed, seconds, t_start, clock,
-                tdir, pool)
+                system, inputs, traffic, seconds, t_start, clock, tdir,
+                pool)
             if trace:
                 t = time.perf_counter()
                 spans = obs.current().finished_spans()
@@ -340,13 +370,13 @@ def run_cell(root: Path, cell: Cell, seed: int, seconds: float,
                 shutil.rmtree(tdir, ignore_errors=True)
         device["memory_peak_bytes"] = _memory_peak()
         if phase == "offline":
-            _spend_bundles(eng, cfg, seq_len, seed, win, traffic)
+            _spend_bundles(eng, inputs, seed, win, traffic)
         counters = system.counters()
     finally:
         obs.disable()
         system.close()
     ctx = Context(cell=cell, window=win, setup_s=setup_s, gc_work=gc_work,
-                  flops_per_request=counts.model_flops(cfg, seq_len),
+                  flops_per_request=family.flops(cfg, seq_len),
                   peaks=peaks, spans=spans, client_tid=client_tid, trace=tr)
     specs = cell.per_layer if trace else cell.end_to_end
     metrics = {}
@@ -354,7 +384,7 @@ def run_cell(root: Path, cell: Cell, seed: int, seconds: float,
         value = load_reader(root, spec["name"])(ctx)
         if value is not None:
             metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
-    checks = _check(cfg, system.weights, seq_len, seed, win, counters)
+    checks = _check(cfg, family, system.weights, inputs, win, counters)
     result = {
         "correct": all(c["value"] <= c["limit"] for c in checks.values()),
         "attempted": win.attempted,
@@ -386,7 +416,7 @@ def _unit(phase: str) -> str:
     return "requests" if phase == "online" else "bundles"
 
 
-def _warm_online(eng, cfg, seq_len, seed, pool) -> None:
+def _warm_online(eng, inputs, pool) -> None:
     """Warm the online shapes with one request on bundle 1 while the
     offline pair makes the window's ``pool`` bundles."""
     err: list = []
@@ -394,7 +424,7 @@ def _warm_online(eng, cfg, seq_len, seed, pool) -> None:
     def warm():
         try:
             t = time.perf_counter()
-            eng.run(M.make_input(cfg, seq_len, seed, WARMUP_INPUT))
+            eng.run(inputs(WARMUP_INPUT))
             log(f"set-up: warm-up request {time.perf_counter() - t:.3f}s")
         except BaseException as e:  # re-raised on the main thread
             err.append(e)
@@ -420,8 +450,7 @@ def _log_setup(spans, clock) -> None:
         f"{c['cache_hits']} hits, {c['cache_misses']} misses)")
 
 
-def _window(system, cfg, traffic, seed, seconds, t_start, clock, tdir,
-            pool):
+def _window(system, inputs, traffic, seconds, t_start, clock, tdir, pool):
     """Drive the client for ``seconds``: closed loop, one client. A unit
     of work starts only while time is left and a bundle (online) or room
     in the session's pool (offline) is there; the window closes when the
@@ -430,7 +459,6 @@ def _window(system, cfg, traffic, seed, seconds, t_start, clock, tdir,
 
     eng = system.client
     phase = traffic["kind"]
-    seq_len = int(traffic["seq_len"])
     which = "online" if phase == "online" else "offline"
     win = Window(phase=phase)
     c0 = clock.snapshot()
@@ -453,7 +481,7 @@ def _window(system, cfg, traffic, seed, seconds, t_start, clock, tdir,
                 tu = time.perf_counter()
                 try:
                     if phase == "online":
-                        x = M.make_input(cfg, seq_len, seed, i)
+                        x = inputs(i)
                         with jax.profiler.TraceAnnotation("bench.request"):
                             y = eng.run(x)
                         win.outputs.append((i, y))
@@ -483,7 +511,7 @@ def _memory_peak() -> int:
     return int(max(peaks))
 
 
-def _spend_bundles(eng, cfg, seq_len, seed, win, traffic) -> None:
+def _spend_bundles(eng, inputs, seed, win, traffic) -> None:
     """Spend a sample of the window's bundles, drawn from the seed and
     always with the last one made, on requests whose outputs are
     checked: this covers the garbling and the table stream."""
@@ -496,7 +524,7 @@ def _spend_bundles(eng, cfg, seq_len, seed, win, traffic) -> None:
         rng.choice(len(ids) - 1, size=k - 1, replace=False).tolist()
         if k > 1 else []))
     for j in pick:
-        x = M.make_input(cfg, seq_len, seed, j)
+        x = inputs(j)
         try:
             y = eng.online.run(x, bundle_id=ids[j])
         except Exception as e:
@@ -507,13 +535,12 @@ def _spend_bundles(eng, cfg, seq_len, seed, win, traffic) -> None:
         win.outputs.append((j, y))
 
 
-def _check(cfg, weights, seq_len, seed, win, counters) -> dict:
+def _check(cfg, family, weights, inputs, win, counters) -> dict:
     """The numbers that decide ``correct``, each with its limit."""
     frac = cfg["privacy"]["frac_bits"]
     gap = 0.0
     for i, y in win.outputs:
-        ref = M.reference(cfg, weights, M.make_input(cfg, seq_len, seed, i),
-                          frac)
+        ref = family.reference(cfg, weights, inputs(i), frac)
         y = np.asarray(y, np.float64)
         if y.shape != ref.shape or not np.isfinite(y).all():
             gap = NO_OUTPUT

@@ -1,5 +1,6 @@
-"""Every name in BENCHMARK.json resolves to its file, and the entry point
-refuses to run without a TPU."""
+"""Every name in BENCHMARK.json and in the configurations resolves to its
+file, each family's reference runs without the program, and the entry
+point refuses to run without a TPU."""
 
 import json
 import os
@@ -9,10 +10,13 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness, model
+from bench import harness
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+FAMILIES = sorted(p.stem for p in (ROOT / "bench" / "families").glob("*.py"))
+#: each family's small configuration for the tests
+TINY = ROOT / "bench" / "tests" / "data" / "families"
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
@@ -23,9 +27,42 @@ def test_config_resolves(entry):
     assert cfg["reduced"] == entry["reduced"]
     for key in entry["reduced"]:
         assert key in cfg
-    assert set(model.shapes(cfg)) == set(model.LAYER_KEYS)
-    assert cfg["hidden_size"] == (cfg["num_attention_heads"]
-                                  * cfg["head_dim"])
+    assert harness.family_file(ROOT, cfg["family"]).is_file()
+    harness.load_family(ROOT, cfg["family"]).check(cfg)
+
+
+#: imports a family with the program made unimportable, then draws its
+#: weights and runs its reference on one input of its small configuration
+NO_PROGRAM = """
+import json, sys
+sys.modules["repro"] = None
+sys.path.insert(0, sys.argv[1])
+from pathlib import Path
+import jax
+jax.config.update("jax_enable_x64", True)
+import numpy as np
+from bench import harness, model
+fam = harness.load_family(Path(sys.argv[1]), sys.argv[2])
+cfg = json.loads(Path(sys.argv[3]).read_text())["tiny"]
+fam.check(cfg)
+w = fam.make_weights(cfg, 2**31 + 7)
+x = getattr(fam, "make_input", model.make_input)(cfg, 4, 2**31 + 7, 0)
+y = fam.reference(cfg, w, x, cfg["privacy"]["frac_bits"])
+assert y.shape[0] == 4 and np.isfinite(y).all(), y
+print("reference", y.shape)
+"""
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_family_reference_runs_without_the_program(family):
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "-c", NO_PROGRAM, str(ROOT), family,
+         str(TINY / f"{family}.json")],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.startswith("reference (4, ")
 
 
 @pytest.mark.parametrize("entry", BENCH["workloads"], ids=lambda w: w["name"])
